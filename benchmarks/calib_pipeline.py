@@ -12,8 +12,11 @@ Measures, on the trained tiny LM over an 8-virtual-device
     pure perf change).
 
 The XLA device count locks at first jax import, so ``run()`` spawns a
-subprocess with ``--xla_force_host_platform_device_count=8`` (the same
-trick as tests/test_dist.py) and parses its JSON report.
+subprocess on the CPU backend with
+``--xla_force_host_platform_device_count=8`` (the same trick as
+tests/test_dist.py) and parses its JSON report.  The parent touches no
+JAX: the child trains the cached model itself, and never asks for an
+accelerator that a parent process might hold.
 """
 
 from __future__ import annotations
@@ -31,10 +34,10 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
 def run(fast: bool = False) -> List["BenchResult"]:
-    from benchmarks.common import BenchResult, trained_model
+    from benchmarks.common import BenchResult
 
-    trained_model("lm")            # train/cache the ckpt before the child
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
@@ -103,13 +106,13 @@ def _child(fast: bool) -> None:
     from repro.core import PruningEngine
     from repro.core.pipeline import run_pipelined
     from repro.data import calibration_batches
-    from repro.dist import use_mesh
+    from repro.dist import make_mesh, use_mesh
 
     model, params, pipe = trained_model("lm")
     n_samples = 128 if fast else 256
     calib = calibration_batches(model.cfg, n_samples=n_samples,
                                 seq_len=64, batch=8)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
     def timed(engine_kwargs, runner=None, with_mesh=True):
         import contextlib

@@ -73,7 +73,9 @@ def main() -> None:
                     help="also write a machine-readable BENCH report "
                          "(the CI bench-gate artifact / baseline.json)")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.smoke:
         args.fast = True
     default = list(SMOKE_MODULES) if args.smoke else list(MODULES)

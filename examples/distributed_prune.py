@@ -27,12 +27,13 @@ from repro.core.distributed import (
     allreduce_calibration,
     prune_matrix_sharded,
 )
+from repro.dist import make_mesh
 
 
 def main():
     print(f"devices: {jax.device_count()}")
     # 2 pods × 2 data shards × 2-way model parallel
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     n, m = 64, 128
     key = jax.random.key(0)
     w = jax.random.normal(key, (n, m)) * 0.1

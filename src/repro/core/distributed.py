@@ -29,13 +29,14 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.calibration import CalibrationSet
 from repro.core.hessian import HessianAccumulator
 from repro.core.pruner import prune_matrix
 from repro.core.sparsity import SparsitySpec
-from repro.dist import current_ctx, shard_map
+from repro.dist import current_ctx
 from repro.dist.sharding import replicated, row_sharding
 
 Axes = Union[str, Sequence[str]]

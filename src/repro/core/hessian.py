@@ -28,10 +28,16 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# The Hessian products are f32 at full precision: a TPU's default f32
+# matmul is one bf16 pass, which rounds f32 activations before the
+# dampened inverse amplifies the error.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def token_outer_product(x: jax.Array) -> jax.Array:
     """2 · x xᵀ for x of shape (m, B) — float32, the paper's Hessian term."""
     x32 = x.astype(jnp.float32)
-    return 2.0 * (x32 @ x32.T)
+    return 2.0 * jnp.matmul(x32, x32.T, precision=HIGHEST)
 
 
 @jax.jit
@@ -45,7 +51,8 @@ def _accum_update(h: jax.Array, count: jax.Array, x: jax.Array):
     b = x32.shape[1]
     new_count = count + b
     scale_old = count / new_count
-    h = h * scale_old + (2.0 / new_count) * (x32 @ x32.T)
+    h = h * scale_old + (2.0 / new_count) * jnp.matmul(
+        x32, x32.T, precision=HIGHEST)
     return h, new_count
 
 
@@ -64,7 +71,8 @@ def _accum_update_weighted(h: jax.Array, count: jax.Array, x: jax.Array,
     denom = jnp.maximum(new_count, 1e-12)
     scale_old = count / denom
     xw = x32 * w32[None, :]
-    h = h * scale_old + (2.0 / denom) * (xw @ x32.T)
+    h = h * scale_old + (2.0 / denom) * jnp.matmul(
+        xw, x32.T, precision=HIGHEST)
     return h, new_count
 
 
@@ -72,7 +80,8 @@ def _accum_update_weighted(h: jax.Array, count: jax.Array, x: jax.Array,
 def _merge_many(hs: jax.Array, cs: jax.Array):
     """Weighted mean of stacked (S, m, m) Hessians by (S,) token counts."""
     total = jnp.sum(cs)
-    h = jnp.einsum("s,sij->ij", cs, hs) / jnp.maximum(total, 1.0)
+    h = (jnp.einsum("s,sij->ij", cs, hs, precision=HIGHEST)
+         / jnp.maximum(total, 1.0))
     return jnp.where(total > 0, h, hs[0]), total
 
 

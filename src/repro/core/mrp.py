@@ -32,32 +32,70 @@ import jax.numpy as jnp
 
 from repro.core import masks as masks_lib
 
+# The (rows, m) @ (m, m) compensation product runs in three bf16 passes
+# on a TPU (~1e-5 relative, far under the bf16 rounding of the stored
+# weights); six-pass HIGHEST compiles ~3x slower for each block's shape.
+COMP_PRECISION = jax.lax.Precision.HIGH
+
 
 # ----------------------------------------------------------------------
 # Batched padded-row compensation (Solutions 𝔐 for compensation)
 # ----------------------------------------------------------------------
-def _gather_submatrix(hinv: jax.Array, idx: jax.Array, valid: jax.Array) -> jax.Array:
+def _gather_submatrix(hinv: jax.Array, idx: jax.Array, valid: jax.Array,
+                      nm: Optional[Tuple[int, int]] = None) -> jax.Array:
     """A = Hinv[idx, idx] with identity padding on invalid slots.
 
     hinv: (m, m); idx: (n, k); valid: (n, k) → (n, k, k).
+
+    ``nm=(N, M)`` declares N:M structure: slot ``i`` of every row lies in
+    column group ``i // N`` (each group of M columns holds exactly N
+    pruned, listed in order).  A row's columns then differ only by their
+    offset inside the group, so A is built by selecting among the M
+    candidate rows/columns of each group — exact (a sum of one term and
+    zeros) and free of the per-element gather, which a TPU runs at a
+    few hundred million elements per second (seconds per layer solve at
+    a published width).
     """
-    rows = hinv[idx]                                     # (n, k, m)
-    sub = jnp.take_along_axis(
-        rows, idx[:, None, :].repeat(idx.shape[1], 1), axis=2
-    )                                                    # (n, k, k)
     k = idx.shape[1]
     eye = jnp.eye(k, dtype=hinv.dtype)
     vv = valid[:, :, None] & valid[:, None, :]
-    return jnp.where(vv, sub, eye[None])
+    if nm is None:
+        sub = hinv[idx[:, :, None], idx[:, None, :]]      # (n, k, k)
+        return jnp.where(vv, sub, eye[None])
+    n_per, m_grp = nm
+    c = idx.shape[0]
+    g = k // n_per
+    off = (idx.reshape(c, g, n_per)
+           - m_grp * jnp.arange(g, dtype=idx.dtype)[None, :, None])
+    hg = hinv[:g * m_grp].reshape(g, m_grp, -1)           # (g, M, m)
+    rows = sum(jnp.where((off == a)[..., None], hg[None, :, None, a], 0.0)
+               for a in range(m_grp))                     # (c, g, N, m)
+    cols = rows.reshape(c, k, -1)[:, :, :g * m_grp].reshape(c, k, g, m_grp)
+    sub = sum(jnp.where((off == b)[:, None], cols[..., b, None], 0.0)
+              for b in range(m_grp))                      # (c, k, g, N)
+    return jnp.where(vv, sub.reshape(c, k, k), eye[None])
 
 
-@functools.partial(jax.jit, static_argnames=("row_chunk",))
+# per-chunk working set of the batched solve (the (rows, k, m) selects
+# and the (rows, k, k) factor): rows are chunked to stay under this
+ROW_CHUNK_BYTES = 1 << 30
+
+
+def auto_row_chunk(n: int, k: int, m: int) -> Optional[int]:
+    """Rows per chunk that keep one chunk's (rows, k, max(k, m)) f32
+    working set under :data:`ROW_CHUNK_BYTES`; None when all rows fit."""
+    rows = max(8, ROW_CHUNK_BYTES // (4 * k * max(k, m)))
+    return None if rows >= n else rows
+
+
+@functools.partial(jax.jit, static_argnames=("row_chunk", "nm"))
 def mrp_compensate(
     w: jax.Array,
     hinv: jax.Array,
     idx: jax.Array,
     valid: jax.Array,
     row_chunk: Optional[int] = None,
+    nm: Optional[Tuple[int, int]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Apply Eq. (13) compensation for the pruned sets given per row.
 
@@ -68,7 +106,9 @@ def mrp_compensate(
       idx:   (n, k_max) per-row pruned columns (padded).
       valid: (n, k_max) validity of idx slots.
       row_chunk: process rows in chunks of this size (memory control for
-             the (chunk, k, k) gather); None = all rows at once.
+             the (chunk, k, k) gather); None = :func:`auto_row_chunk`.
+      nm:    (N, M) when every row prunes exactly N of each leading group
+             of M columns (see :func:`_gather_submatrix`).
 
     Returns:
       (w_new, loss_per_row) — w_new has *exact* zeros at pruned slots;
@@ -77,9 +117,11 @@ def mrp_compensate(
     n, m = w.shape
     w32 = w.astype(jnp.float32)
     hinv = hinv.astype(jnp.float32)
+    if row_chunk is None:
+        row_chunk = auto_row_chunk(n, idx.shape[1], m)
 
     def solve_rows(w_rows, idx_rows, valid_rows):
-        a = _gather_submatrix(hinv, idx_rows, valid_rows)          # (c,k,k)
+        a = _gather_submatrix(hinv, idx_rows, valid_rows, nm)      # (c,k,k)
         wp = jnp.take_along_axis(w_rows, idx_rows, axis=1)
         wp = jnp.where(valid_rows, wp, 0.0)                        # (c,k)
         # A is a principal submatrix of a PD matrix ⇒ PD ⇒ Cholesky solve.
@@ -87,11 +129,12 @@ def mrp_compensate(
         z = jax.scipy.linalg.cho_solve(chol, wp[..., None])[..., 0]  # (c,k)
         z = jnp.where(valid_rows, z, 0.0)
         loss = 0.5 * jnp.sum(z * wp, axis=1)                       # (c,)
-        # Scatter z back to full width and do ONE dense matmul with Hinv.
+        # Scatter z back to full width and do ONE dense matmul with Hinv
+        # (a TPU's default f32 matmul is one bf16 pass: see COMP_PRECISION)
         zfull = jnp.zeros_like(w_rows).at[
             jnp.arange(w_rows.shape[0])[:, None], idx_rows
         ].add(jnp.where(valid_rows, z, 0.0))
-        delta = -(zfull @ hinv)                                    # (c,m)
+        delta = -jnp.matmul(zfull, hinv, precision=COMP_PRECISION)
         return w_rows + delta, loss
 
     if row_chunk is None or row_chunk >= n:
@@ -114,10 +157,11 @@ def mrp_compensate(
         loss = loss.reshape(-1)[:n]
 
     # Enforce exact zeros at pruned slots (δw analytically cancels w there;
-    # this removes residual float error).
-    mask = jnp.zeros((n, m), bool).at[
+    # this removes residual float error).  A float scatter-add: a bool
+    # scatter-max compiles ~10x slower for a TPU.
+    mask = jnp.zeros((n, m), jnp.float32).at[
         jnp.arange(n)[:, None], idx
-    ].max(valid)
+    ].add(valid.astype(jnp.float32)) > 0
     w_new = jnp.where(mask, 0.0, w_new)
     return w_new.astype(w.dtype), loss
 
@@ -128,16 +172,19 @@ def mrp_compensate_mask(
     mask: jax.Array,
     k_max: Optional[int] = None,
     row_chunk: Optional[int] = None,
+    nm: Optional[Tuple[int, int]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Convenience wrapper: boolean mask (True = pruned) → Eq. (13).
 
     ``k_max`` defaults to the concrete per-row max (host sync + bucketing).
+    ``nm`` declares the mask N:M over its leading ``k_max / N`` groups
+    (exact count per group — see :func:`_gather_submatrix`).
     """
     if k_max is None:
         k_max = masks_lib.bucket_k(masks_lib.max_row_count(mask))
     k_max = min(int(k_max), mask.shape[1])
     idx, valid = masks_lib.padded_row_indices(mask, k_max)
-    return mrp_compensate(w, hinv, idx, valid, row_chunk=row_chunk)
+    return mrp_compensate(w, hinv, idx, valid, row_chunk=row_chunk, nm=nm)
 
 
 # ----------------------------------------------------------------------

@@ -61,7 +61,8 @@ def reconstruction_error_traced(
 ) -> jax.Array:
     """Traceable twin of :func:`reconstruction_error` (no host sync)."""
     dw = (w1 - w0).astype(jnp.float32)
-    return 0.5 * jnp.einsum("ij,jk,ik->", dw, h.astype(jnp.float32), dw)
+    return 0.5 * jnp.einsum("ij,jk,ik->", dw, h.astype(jnp.float32), dw,
+                            precision=jax.lax.Precision.HIGHEST)
 
 
 def _maybe_float(x):
@@ -174,6 +175,9 @@ def prune_matrix(
     # static per-row bound when selection is row-balanced (incl. all N:M)
     static_rows = spec.is_semi_structured or row_balanced
     per_blk = spec.pruned_per_row_block(blocksize) if static_rows else None
+    # N:M masks prune exactly N of every group: the MRP submatrices are
+    # then built by an exact structured select instead of a gather
+    nm = (spec.n, spec.m) if spec.is_semi_structured else None
     mask_acc = jnp.zeros((n, m), bool)
     w_cur = w
     # Per-block Eq. (12) losses.  Each block's solve is against the FULL
@@ -196,7 +200,7 @@ def prune_matrix(
         # MRP compensation against the FULL accumulated mask (Algorithm 1).
         k_max = (b + 1) * per_blk if static_rows else None
         w_cur, loss_rows = mrp.mrp_compensate_mask(
-            w_cur, hinv, mask_acc, k_max=k_max, row_chunk=row_chunk
+            w_cur, hinv, mask_acc, k_max=k_max, row_chunk=row_chunk, nm=nm
         )
         block_losses.append(jnp.sum(loss_rows))
     return PruneResult(

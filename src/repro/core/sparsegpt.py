@@ -131,7 +131,8 @@ def _sparsegpt_core(
 
         # lazy trailing update: w[:, i2:] -= Err1 @ U[i1:i2, i2:]
         urows = jax.lax.dynamic_slice(u, (i1, 0), (blocksize, m))
-        trailing = err1 @ urows                       # (n, m)
+        trailing = jnp.matmul(err1, urows,
+                              precision=jax.lax.Precision.HIGHEST)  # (n, m)
         colmask = jnp.arange(m) >= (i1 + blocksize)
         w = w - trailing * colmask[None, :]
         w = jax.lax.dynamic_update_slice(w, w1, (0, i1))

@@ -6,13 +6,12 @@ reference: ``docs/dist_api.md``):
   - :mod:`repro.dist.api`      — ``use_mesh`` / ``current_ctx`` /
     ``constrain``: the ambient device context every model, trainer,
     pruner and server resolves instead of threading a mesh by hand;
-  - :mod:`repro.dist.mesh`     — mesh construction (production pods,
-    host test mesh, ``--mesh`` CLI specs);
+  - :mod:`repro.dist.mesh`     — mesh construction (``make_mesh``, the
+    one constructor — all axes ``Auto``; production pods, host test
+    mesh, ``--mesh`` CLI specs);
   - :mod:`repro.dist.sharding` — the rules layer: param / batch
     PartitionSpecs and NamedShardings (FSDP over the data axes, tensor
-    parallel over ``model``, MoE expert parallel);
-  - :mod:`repro.dist.compat`   — version bridge for ``shard_map`` across
-    the jax 0.4.x → 0.6+ API rename.
+    parallel over ``model``, MoE expert parallel).
 
 Axis-naming convention: ``pod`` (DCN, outer batch axis), ``data``
 (batch + FSDP), ``model`` (tensor/expert parallel).
@@ -24,11 +23,11 @@ from repro.dist.api import (
     current_ctx,
     use_mesh,
 )
-from repro.dist.compat import cost_analysis_dict, shard_map
 from repro.dist.mesh import (
     add_mesh_argument,
     dp_axes_of,
     make_host_mesh,
+    make_mesh,
     make_production_mesh,
     mesh_context,
     mesh_from_spec,
@@ -54,11 +53,10 @@ __all__ = [
     "constrain",
     "current_ctx",
     "use_mesh",
-    "cost_analysis_dict",
-    "shard_map",
     "add_mesh_argument",
     "dp_axes_of",
     "make_host_mesh",
+    "make_mesh",
     "make_production_mesh",
     "mesh_context",
     "mesh_from_spec",

@@ -13,16 +13,27 @@ common ``--mesh`` entry path shared by the launch CLIs
 from __future__ import annotations
 
 import contextlib
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """The one mesh constructor: every axis ``Auto`` (GSPMD propagates
+    shardings; ``with_sharding_constraint`` and the embed gather accept
+    them).  ``jax.make_mesh`` alone now defaults to ``Explicit`` axes,
+    which reject both.  ``devices`` defaults to ``jax.devices()``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def dp_axes_of(mesh) -> Tuple[str, ...]:
@@ -32,7 +43,7 @@ def dp_axes_of(mesh) -> Tuple[str, ...]:
 
 def make_host_mesh():
     """1×1 mesh over the local device (CPU tests of mesh-aware code)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def mesh_from_spec(spec: Optional[str]):
@@ -59,7 +70,7 @@ def mesh_from_spec(spec: Optional[str]):
         shape = tuple(int(d) for d in dims)
         axes = ("data", "model") if len(dims) == 2 else (
             "pod", "data", "model")
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     raise ValueError(f"unrecognized --mesh spec {spec!r}")
 
 

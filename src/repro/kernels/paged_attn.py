@@ -9,10 +9,16 @@ step's K/V page DMA is issued from ``block_tables[b, p]`` — the gather
 never materializes a per-request contiguous cache (the jnp oracle in
 ref.paged_attn_ref does exactly that, and is the CPU serving path).
 
-Grid (B, KV, P_max); the page axis is the innermost *sequential* axis —
+Grid (B, P_max); the page axis is the innermost *sequential* axis —
 accumulator + running max/sum live in VMEM scratch across page steps
-(same online-softmax structure as flash_attn.py).  Pages past a
-request's length are skipped via @pl.when (their DMA still issues but
+(same online-softmax structure as flash_attn.py).  Each step moves one
+whole page, all KV heads: blocks are (1, page_size, KV, hd) (scales
+(1, page_size, KV)), so the last two block dims equal the pool's and
+Mosaic's (8, 128) tiling rule holds for any head count.  The heads are
+handled inside the body as a batch dim of VPU multiply-reduces — the
+scores are a (page_size, G, KV, 1) tile, the softmax reduces over the
+leading page axis — so nothing is sliced along a tiled dim.  Pages past
+a request's length are skipped via @pl.when (their DMA still issues but
 runs no FLOPs; the mosaic pipeliner overlaps it with live compute), and
 an idle slot (length 0) computes nothing and emits zeros.
 """
@@ -42,8 +48,8 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int,
     else:
         k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    n_pages = pl.num_programs(2)
+    p = pl.program_id(1)
+    n_pages = pl.num_programs(1)
     length = len_ref[b]
 
     @pl.when(p == 0)
@@ -61,36 +67,32 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int,
 
     @pl.when(live)
     def _compute():
-        g = q_ref.shape[2]
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (ps, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)            # (ps, hd)
+        q = q_ref[0].astype(jnp.float32) * scale          # (G, KV, hd)
+        k = k_ref[0].astype(jnp.float32)                  # (ps, KV, hd)
+        v = v_ref[0].astype(jnp.float32)                  # (ps, KV, hd)
         if quantized:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (G, ps)
-        kpos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (g, page_size), 1)
+            k = k * ks_ref[0][:, :, None]
+            v = v * vs_ref[0][:, :, None]
+        s = jnp.sum(k[:, None] * q[None], axis=-1,
+                    keepdims=True)                        # (ps, G, KV, 1)
+        kpos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         ok = kpos < length
         if window is not None:
             ok &= kpos >= length - window
         s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]                               # (G, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        pmat = jnp.exp(s - m_new)
+        m_prev = m_ref[...]                               # (G, KV, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        pmat = jnp.exp(s - m_new[None])
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pmat, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            pmat, v, preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(pmat, axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.sum(pmat * v[:, None],
+                                                      axis=0)
         m_ref[...] = m_new
 
     @pl.when(p == n_pages - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -117,15 +119,17 @@ def paged_attn(
     p_max = block_tables.shape[1]
     scale = 1.0 / math.sqrt(hd)
     quantized = k_scale is not None
-    page_spec = pl.BlockSpec((1, page_size, 1, hd),
-                             lambda bb, kk, pp, bt, ln: (bt[bb, pp], 0, kk, 0))
-    scale_spec = pl.BlockSpec((1, page_size, 1),
-                              lambda bb, kk, pp, bt, ln: (bt[bb, pp], 0, kk))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda bb, kk, pp, bt, ln: (bb, kk, 0, 0)),
-        page_spec,
-    ]
-    operands = [q, k_pages]
+    # the kernel reads q group-major, (G, KV, hd) per request, so the
+    # tiled last two dims match the page tile's (KV, hd)
+    qt = jnp.swapaxes(q, 1, 2)                            # (B, G, KV, hd)
+    page_spec = pl.BlockSpec((1, page_size, kvh, hd),
+                             lambda bb, pp, bt, ln: (bt[bb, pp], 0, 0, 0))
+    scale_spec = pl.BlockSpec((1, page_size, kvh),
+                              lambda bb, pp, bt, ln: (bt[bb, pp], 0, 0))
+    q_spec = pl.BlockSpec((1, g, kvh, hd),
+                          lambda bb, pp, bt, ln: (bb, 0, 0, 0))
+    in_specs = [q_spec, page_spec]
+    operands = [qt, k_pages]
     if quantized:
         in_specs.append(scale_spec)
         operands.append(k_scale)
@@ -136,21 +140,21 @@ def paged_attn(
         operands.append(v_scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, p_max),
+        grid=(b, p_max),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda bb, kk, pp, bt, ln: (bb, kk, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),   # output accumulator
-            pltpu.VMEM((g, 1), jnp.float32),    # running max m
-            pltpu.VMEM((g, 1), jnp.float32),    # running sum l
+            pltpu.VMEM((g, kvh, hd), jnp.float32),   # output accumulator
+            pltpu.VMEM((g, kvh, 1), jnp.float32),    # running max m
+            pltpu.VMEM((g, kvh, 1), jnp.float32),    # running sum l
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, page_size=page_size,
                           window=window, scale=scale, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, g, kvh, hd), jnp.float32),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
+    return jnp.swapaxes(out, 1, 2)

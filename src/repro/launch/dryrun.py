@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs as cfglib
-from repro.dist.compat import cost_analysis_dict
 from repro.dist.sharding import (
     batch_sharding,
     named_shardings,
@@ -43,6 +42,7 @@ from repro.launch.mesh import dp_axes_of, make_production_mesh
 from repro.models.transformer import LM
 from repro.optim import AdamW
 from repro.train import make_train_step
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.hlo import collective_bytes
 
 # TPU v5e per-chip constants (roofline)
@@ -217,7 +217,7 @@ def run_cell(arch_id: str, shape: str, *, multi_pod: bool,
             opt=opt)
         t_compile = time.monotonic() - t0
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
 
         # --- trip-count-scaled collective census --------------------------
         # Collectives inside scan bodies appear once in the HLO text; the
@@ -319,6 +319,7 @@ def main() -> int:
                     help="§Perf hillclimb level (0=baseline)")
     ap.add_argument("--out", default=None, help="JSONL output path")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch_ids = [a for a in cfglib.ARCH_IDS if a != "paper_tiny_lm"] \
         if (args.all or args.arch is None) else [cfglib.canonical(args.arch)]

@@ -27,6 +27,7 @@ from repro.data import DataPipeline, calibration_batches
 from repro.dist import add_mesh_argument, mesh_context
 from repro.models import LM
 from repro.obs import Obs
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def load_trained_params(model: LM, ckpt_dir: str):
@@ -97,6 +98,7 @@ def main() -> None:
     add_mesh_argument(ap)
     args = ap.parse_args()
     install_sigterm_handler()
+    enable_compile_cache()
 
     cfg = (cfglib.get_smoke(args.arch) if args.smoke
            else cfglib.get_config(args.arch))

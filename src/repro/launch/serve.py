@@ -44,6 +44,7 @@ from repro.serve import ServeConfig, ServeEngine, sparsify_params
 from repro.serve.frontend import (CompletionRequest, CompletionResponse,
                                   Replica, Router, Supervisor, run_server,
                                   to_engine_request)
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def install_sigterm_handler() -> None:
@@ -237,7 +238,13 @@ def run_batch(cfg, model, params, args, config: ServeConfig,
             router.close()
         else:
             t0 = time.monotonic()
-            results = router.complete(creqs)
+            try:
+                # raises ReplicaCrashed if a worker dies: batch mode
+                # runs no supervisor, so nothing would finish the batch
+                results = router.complete(creqs)
+            except BaseException:
+                router.close()
+                raise
             dt = time.monotonic() - t0
             router.drain(timeout=30)
             _summary(results, [r.engine for r in router.replicas], dt)
@@ -333,6 +340,7 @@ def run_frontend(cfg, model, params, args, config: ServeConfig,
 def main() -> None:
     args = build_parser().parse_args()
     install_sigterm_handler()
+    enable_compile_cache()
     config = ServeConfig.from_args(args)   # the ONE knob intake point
     # ONE obs bundle for the whole process: every replica labels its
     # series into this registry/tracer (docs/observability.md)

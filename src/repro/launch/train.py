@@ -19,6 +19,7 @@ from repro.models import LM
 from repro.optim import AdamW
 from repro.optim.schedules import warmup_cosine
 from repro.train import Trainer, TrainConfig
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     add_mesh_argument(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (cfglib.get_smoke(args.arch) if args.smoke
            else cfglib.get_config(args.arch))

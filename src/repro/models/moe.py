@@ -21,9 +21,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.dist.api import current_ctx
-from repro.dist.compat import shard_map
 from repro.dist.sharding import moe_dispatch_specs
 from repro.models.base import ArchConfig
 from repro.models.layers import (Params, _dense_init, mlp_apply,

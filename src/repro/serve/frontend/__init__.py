@@ -23,7 +23,8 @@ from repro.serve.frontend.protocol import (CompletionChunk,
                                            CompletionResponse, sse_decode,
                                            sse_encode, to_engine_request)
 from repro.serve.frontend.replica import Replica, ReplicaDraining
-from repro.serve.frontend.router import NoHealthyReplicas, Router
+from repro.serve.frontend.router import (NoHealthyReplicas, ReplicaCrashed,
+                                         Router)
 from repro.serve.frontend.server import Server, run_server
 from repro.serve.frontend.supervisor import Supervisor
 
@@ -33,6 +34,7 @@ __all__ = [
     "CompletionResponse",
     "NoHealthyReplicas",
     "Replica",
+    "ReplicaCrashed",
     "ReplicaDraining",
     "Router",
     "Server",

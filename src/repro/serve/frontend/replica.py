@@ -45,9 +45,6 @@ from typing import Callable, Dict, List, Optional
 
 from repro.serve.engine import Request, ServeEngine, StreamEvent
 from repro.serve.faults import FaultError
-from repro.serve.frontend.protocol import (CompletionRequest,
-                                           CompletionResponse,
-                                           to_engine_request)
 
 # a replica whose worker hasn't completed a step (or an idle check) in
 # this long while work is pending is reported unhealthy
@@ -275,33 +272,3 @@ class Replica:
         self._closed = True
         self._wake.set()
         self._thread.join(timeout=5.0)
-
-    # ----------------------------------------------------- batch client
-    def complete(self, creqs: List[CompletionRequest],
-                 uid_start: int = 0) -> List[CompletionResponse]:
-        """Blocking convenience used by the batch CLI path: run wire
-        requests through the SAME submit/stream machinery the server
-        uses and collect terminal responses (uid order)."""
-        done = threading.Event()
-        out: Dict[int, CompletionResponse] = {}
-        remaining = len(creqs)
-        lock = threading.Lock()
-
-        def make_cb(uid: int):
-            def cb(ev: StreamEvent) -> None:
-                nonlocal remaining
-                if not ev.finished:
-                    return
-                with lock:
-                    out[uid] = CompletionResponse.from_result(
-                        ev.result, replica=self.name)
-                    remaining -= 1
-                    if remaining == 0:
-                        done.set()
-            return cb
-
-        for i, creq in enumerate(creqs):
-            uid = creq.uid if creq.uid is not None else uid_start + i
-            self.submit(to_engine_request(creq, uid), make_cb(uid))
-        done.wait()
-        return [out[k] for k in sorted(out)]

@@ -45,6 +45,16 @@ class NoHealthyReplicas(RuntimeError):
     retry_after_s: float = 1.0
 
 
+class ReplicaCrashed(RuntimeError):
+    """A replica's worker died while a blocking :meth:`Router.complete`
+    batch was in flight and no supervisor is there to fail its requests
+    over — the batch can never finish, so it raises instead of waiting."""
+
+
+# how often a blocking batch checks its replicas for a dead worker
+_CRASH_POLL_S = 0.1
+
+
 class Router:
     def __init__(self, replicas: List[Replica],
                  submit_retries: int = 0,
@@ -52,6 +62,9 @@ class Router:
         if not replicas:
             raise ValueError("router needs at least one replica")
         self.replicas = list(replicas)
+        # set by a Supervisor: crashed workers are then restarted and
+        # their requests failed over, so a batch keeps waiting
+        self.supervised = False
         # bounded jittered-backoff retries (ISSUE-10): how many times
         # submit re-sweeps the replicas when every one is transiently
         # full/draining/down — 0 keeps the original fail-fast behavior
@@ -136,7 +149,8 @@ class Router:
                  ) -> List[CompletionResponse]:
         """Blocking batch entry point (the CLI's code path): stream all
         requests through the replicas, return terminal responses in uid
-        order."""
+        order.  Raises :class:`ReplicaCrashed` when a worker dies and no
+        supervisor will recover it."""
         done = threading.Event()
         out: Dict[int, CompletionResponse] = {}
         lock = threading.Lock()
@@ -152,7 +166,8 @@ class Router:
                     return
                 with lock:
                     out[uid] = CompletionResponse.from_result(
-                        ev.result, replica=names.get(uid))
+                        ev.result, replica=names.get(uid),
+                        finish_reason=ev.finish_reason)
                     remaining -= 1
                     if remaining == 0:
                         done.set()
@@ -162,7 +177,14 @@ class Router:
             uid = self.assign_uid(creq)
             rep = self.submit(creq, make_cb(uid), uid=uid)
             names[uid] = rep.name
-        done.wait()
+        while not done.wait(timeout=_CRASH_POLL_S):
+            if self.supervised:
+                continue
+            for r in self.replicas:
+                if r.crashed is not None:
+                    raise ReplicaCrashed(
+                        f"replica {r.name} worker died: {r.crashed!r}"
+                    ) from r.crashed
         return [out[k] for k in sorted(out)]
 
     # --------------------------------------------------------- lifecycle

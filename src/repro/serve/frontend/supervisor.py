@@ -76,6 +76,7 @@ class Supervisor:
     def __init__(self, router: Router, poll_s: float = 0.5,
                  failover_retries: int = 8):
         self.router = router
+        router.supervised = True
         self.poll_s = poll_s
         self.failover_retries = failover_retries
         self._stop = threading.Event()

@@ -185,3 +185,16 @@ def collective_bytes(hlo_text: str,
         counts[rec["op"]] += max(1, round(mult))
         obytes[rec["op"]] += rec["operand_bytes"] * mult
     return CollectiveStats(counts=dict(counts), operand_bytes=dict(obytes))
+
+
+_KERNEL_CALL = re.compile(
+    r'custom_call_target="tpu_custom_call".*?op_name="[^"]*?'
+    r'jit\(([A-Za-z0-9_]+)\)/pallas_call')
+
+
+def tpu_kernel_names(hlo_text: str) -> List[str]:
+    """Names of the Pallas kernels compiled into a TPU module: the jitted
+    wrapper around each ``tpu_custom_call`` (``paged_attn``,
+    ``nm_spmm_decode``, ...), read from the op metadata of
+    ``compiled.as_text()``, in order of appearance."""
+    return _KERNEL_CALL.findall(hlo_text)

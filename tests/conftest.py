@@ -21,20 +21,22 @@ from repro.train import TrainConfig, Trainer
 
 
 @pytest.fixture(scope="session")
-def tiny_lm():
+def tiny_lm(tmp_path_factory):
     """The paper-tiny-lm trained ~200 steps on the synthetic corpus.
 
-    Session-scoped: trained once, shared by pruning/serving/benchmark
-    tests. Returns (model, params, pipeline)."""
+    Session-scoped: trained once per test process (each xdist worker
+    trains into its own fresh directory, never resuming a checkpoint
+    that other code wrote), shared by pruning/serving/benchmark tests.
+    Returns (model, params, pipeline)."""
     cfg = get_config("paper_tiny_lm")
     model = LM(cfg)
     pipe = DataPipeline(cfg, global_batch=16, seq_len=64, seed=0)
     opt = AdamW(lr=warmup_cosine(1e-3, 20, 200))
-    out = "/tmp/repro_test_tiny_lm"
+    out = str(tmp_path_factory.mktemp("tiny_lm"))
     tc = TrainConfig(total_steps=200, global_batch=16, seq_len=64,
                      ckpt_every=200, out_dir=out, log_every=100)
     trainer = Trainer(model, opt, pipe, tc)
-    params, _, _ = trainer.run()   # resumes from ckpt if already trained
+    params, _, _ = trainer.run()
     return model, params, pipe
 
 

@@ -9,6 +9,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.ckpt import CheckpointStore, load_pytree, save_pytree
+from repro.dist import make_mesh
 
 
 def _tree(seed=0):
@@ -73,14 +74,14 @@ def test_store_walks_past_corrupt(tmp_path):
 def test_elastic_restore_different_sharding(tmp_path):
     """Save under one sharding, restore under another mesh/sharding —
     values identical (the trainer's elastic-restart path)."""
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"))
     t = _tree()
     t_sharded = jax.device_put(
         t, NamedSharding(mesh1, P()))
     path = str(tmp_path / "ck")
     save_pytree(path, t_sharded)
 
-    mesh2 = jax.make_mesh((1,), ("x",))
+    mesh2 = make_mesh((1,), ("x",))
     loaded, _ = load_pytree(path, t)
     placed = jax.device_put(loaded, NamedSharding(mesh2, P()))
     for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(placed)):

@@ -50,6 +50,31 @@ def test_corpus_markov_structure():
     assert np.mean(ents) < 0.7 * np.log(128)
 
 
+def test_corpus_rows_without_table():
+    """A vocabulary too large for the (V, V) table samples rows built on
+    demand: deterministic, in range, and still a concentrated chain."""
+    from repro.data.synthetic import TABLE_MAX_ENTRIES
+
+    vocab = 8192
+    assert vocab * vocab > TABLE_MAX_ENTRIES
+    corpus = MarkovCorpus(vocab, seed=0)
+    toks = np.asarray(corpus.batch_at(0, 0, 64, 256))
+    np.testing.assert_array_equal(
+        toks, np.asarray(MarkovCorpus(vocab, seed=0).batch_at(0, 0, 64, 256)))
+    assert toks.min() >= 0 and toks.max() < vocab
+    pairs = {}
+    for row in toks:
+        for a, b in zip(row[:-1], row[1:]):
+            pairs.setdefault(int(a), []).append(int(b))
+    ents = []
+    for a, succ in pairs.items():
+        if len(succ) >= 30:
+            _, counts = np.unique(succ, return_counts=True)
+            p = counts / counts.sum()
+            ents.append(-(p * np.log(p)).sum())
+    assert ents and np.mean(ents) < 0.7 * np.log(vocab)
+
+
 def test_calibration_batches_shapes():
     cfg = get_config("paper_tiny_lm")
     batches = calibration_batches(cfg, n_samples=16, seq_len=32, batch=8)

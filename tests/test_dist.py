@@ -31,7 +31,8 @@ def test_row_parallel_prune_matches_single_device():
         from repro.core.pruner import prune_matrix
         from repro.core.sparsity import SparsitySpec
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.dist import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         n, m = 32, 64
         w = jax.random.normal(jax.random.key(0), (n, m))
         x = jax.random.normal(jax.random.key(1), (m, 4 * m))
@@ -56,7 +57,8 @@ def test_hessian_psum_across_data_shards():
         from repro.core.distributed import hessian_allreduce
         from repro.core.hessian import HessianAccumulator
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.dist import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 16
         xs = [jax.random.normal(jax.random.key(i), (m, 10 + 7 * i))
               for i in range(8)]
@@ -79,10 +81,11 @@ def test_compressed_psum_close_to_exact():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.dist import shard_map
+        from jax import shard_map
         from repro.optim.compression import compressed_psum
 
-        mesh = jax.make_mesh((8,), ("pods",))
+        from repro.dist import make_mesh
+        mesh = make_mesh((8,), ("pods",))
         n = 1024
         xs = jax.random.normal(jax.random.key(0), (8, n))
 
@@ -118,7 +121,8 @@ def test_moe_expert_parallel_matches_single_device():
         batch = {"tokens": toks, "labels": toks}
         ref, _ = model.forward(params, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.dist import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with use_mesh(mesh):
             dist, _ = jax.jit(model.forward)(params, batch)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(dist),
@@ -150,7 +154,8 @@ def test_sharded_train_step_matches_single_device():
         p_ref, o_ref, _, m_ref = jax.jit(step)(
             params, opt_state, jnp.zeros(()), batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.dist import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         psh = param_shardings(params, mesh)
         bsh = batch_sharding(mesh)
         params_d = jax.device_put(params, psh)
@@ -180,7 +185,8 @@ def test_sharded_calibration_matches_local_accumulation():
         from repro.core.calibration import CalibrationSet
         from repro.core.distributed import allreduce_calibration
 
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        from repro.dist import make_mesh
+        mesh = make_mesh((2, 4), ("pod", "data"))
         m, key = 16, jax.random.key(0)
         shard_caps = []
         for s in range(8):
@@ -231,7 +237,8 @@ def test_pipelined_engine_sharded_calibration_matches_serial():
             model, "2:4", method="SM", blocksize=32,
             pipeline="off").run(params, calib)
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.dist import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         with use_mesh(mesh):
             eng = PruningEngine(model, "2:4", method="SM", blocksize=32,
                                 calib_shard="on")

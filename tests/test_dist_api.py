@@ -14,6 +14,7 @@ from repro.dist import (
     current_ctx,
     dp_axes_of,
     make_host_mesh,
+    make_mesh,
     mesh_from_spec,
     param_specs,
     shard_params,
@@ -38,7 +39,7 @@ def test_use_mesh_populates_context():
 
 
 def test_use_mesh_without_model_axis_degrades_tp():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with use_mesh(mesh) as ctx:
         assert ctx.tp_axis is None
         assert ctx.tp == 1
@@ -46,7 +47,7 @@ def test_use_mesh_without_model_axis_degrades_tp():
 
 def test_nested_use_mesh_restores_outer_context():
     outer = make_host_mesh()
-    inner = jax.make_mesh((1,), ("data",))
+    inner = make_mesh((1,), ("data",))
     with use_mesh(outer) as octx:
         with use_mesh(inner) as ictx:
             assert current_ctx() is ictx

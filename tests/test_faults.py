@@ -316,12 +316,42 @@ def test_replica_worker_fault_and_restart_idle(tiny):
         assert not rep.healthy
         assert sup.check_once() == ["r0"]
         assert rep.healthy and rep.crashed is None
-        out = rep.complete([CompletionRequest(prompt=[1, 2, 3],
-                                              max_tokens=3, uid=0)])
+        out = router.complete([CompletionRequest(prompt=[1, 2, 3],
+                                                 max_tokens=3, uid=0)])
         assert len(out[0].tokens) == 3
     finally:
         sup.stop()
         router.close()
+
+
+def test_batch_crash_raises_instead_of_hanging():
+    """Batch mode runs no supervisor: an engine-step fault kills the only
+    replica's worker, and ``run_batch`` must end with the crash instead
+    of waiting forever on requests nobody will finish."""
+    from repro.launch import serve as launch_serve
+    from repro.obs import Obs
+    from repro.serve import ServeConfig
+    from repro.serve.frontend import ReplicaCrashed
+
+    args = launch_serve.build_parser().parse_args(
+        ["--smoke", "--requests", "3", "--max-new", "4",
+         "--inject-fault", "engine_step:after=1"])
+    config = ServeConfig.from_args(args)
+    cfg, model, params = launch_serve.load_model(args)
+    result = {}
+
+    def run():
+        try:
+            launch_serve.run_batch(cfg, model, params, args, config,
+                                   Obs.create(metrics=True, trace=False))
+        except ReplicaCrashed as e:
+            result["err"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(timeout=120)
+    assert not t.is_alive(), "run_batch hung on a crashed replica"
+    assert "injected" in str(result["err"].__cause__)
 
 
 # ======================================================================

@@ -11,11 +11,11 @@ def test_lower_compile_smoke_cells():
         import jax, dataclasses
         import numpy as np
         from repro import configs as cfglib
-        from repro.dist import cost_analysis_dict, use_mesh
+        from repro.dist import make_mesh, use_mesh
         from repro.launch.dryrun import build_lowerable, OptFlags
         from repro.utils.hlo import collective_bytes
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(
             cfglib.get_smoke("qwen3_14b"), name="launch-smoke")
         for shape in ("train_4k", "prefill_32k", "decode_32k"):
@@ -25,7 +25,7 @@ def test_lower_compile_smoke_cells():
             with use_mesh(mesh):
                 compiled = jax.jit(
                     fn, in_shardings=shardings).lower(*args).compile()
-            cost = cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
             assert float(cost.get("flops", 0)) > 0
             stats = collective_bytes(compiled.as_text(), trip_counts=(2,))
             print(shape, "ok", stats.total_count, "collectives")

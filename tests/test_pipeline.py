@@ -12,6 +12,7 @@ from repro.core import PruningEngine
 from repro.core.engine import summarize
 from repro.core.pipeline import SegmentScheduler, _resolve_shards
 from repro.data import calibration_batches
+from repro.dist import make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -161,7 +162,7 @@ def test_scheduler_stacking_and_shard_resolution():
     assert _resolve_shards(True, None, (), 8) == 1
     assert _resolve_shards(False, None, (), 8) == 1
     assert _resolve_shards(3, None, (), 8) == 3
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert _resolve_shards("auto", mesh, ("data",), 8) == 1
     with pytest.raises(ValueError):
         _resolve_shards("definitely", None, (), 8)

@@ -516,7 +516,8 @@ def test_shared_prefix_2x4_mesh_parity():
                                                 np.int32)]),
                         max_new_tokens=6)
                 for i in range(8)]
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.dist import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         for sampled in (False, True):
             kw = dict(max_batch=4, max_len=64, page_size=8,
                       num_pages=17, steps_per_sync=4)

@@ -186,7 +186,8 @@ def test_mesh_headsplit_parity():
             return out
 
         base = losses(None)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.dist import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with use_mesh(mesh):
             guarded = losses(mesh, head_dim=cfg.hd)   # Trainer's layout
         err = max(abs(a - b) for a, b in zip(base, guarded))
