@@ -175,7 +175,6 @@ def _child(fast: bool) -> None:
         "local_propagate_s": ilstats.propagate_s,
         "local_stage_total_s": ilstats.stage_total(),
         "calib_shards": stats.calib_shards,
-        "compiles": stats.compiles,
         "capture_s": istats.capture_s,
         "solve_s": istats.solve_s,
         "propagate_s": istats.propagate_s,

@@ -38,6 +38,7 @@ from repro.core.pruner import prune_matrix
 from repro.core.sparsity import SparsitySpec
 from repro.dist import current_ctx
 from repro.dist.sharding import replicated, row_sharding
+from repro.obs import note_trace
 
 Axes = Union[str, Sequence[str]]
 
@@ -221,22 +222,24 @@ def _sharded_prune_fn(
     shard_map closure per call re-traced the whole MRP block loop —
     28 compiles per tiny-LM prune, the wall-clock dominator)."""
 
-    def _local(w_loc, h_rep):
-        res = prune_matrix(
-            w_loc,
-            h_rep,
-            spec,
-            method=method,
-            blocksize=blocksize,
-            gamma=gamma,
-            score=score,
-            row_chunk=row_chunk,
-            row_balanced=True,          # static shapes, per-row selection
-        )
-        return res.w, res.mask
+    def prune_solve_rows(w_loc, h_rep):
+        note_trace("solve")
+        with jax.named_scope("prune_solve"):
+            res = prune_matrix(
+                w_loc,
+                h_rep,
+                spec,
+                method=method,
+                blocksize=blocksize,
+                gamma=gamma,
+                score=score,
+                row_chunk=row_chunk,
+                row_balanced=True,      # static shapes, per-row selection
+            )
+            return res.w, res.mask
 
     return jax.jit(shard_map(
-        _local,
+        prune_solve_rows,
         mesh=mesh,
         in_specs=(P(model_axis, None), P(None, None)),
         out_specs=(P(model_axis, None), P(model_axis, None)),

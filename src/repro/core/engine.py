@@ -47,6 +47,7 @@ import jax
 from repro.core.calibration import CalibrationSet, Capture
 from repro.core.pruner import PruneResult, prune_matrix
 from repro.core.sparsity import SparsitySpec
+from repro.obs import note_trace
 
 log = logging.getLogger("repro.engine")
 
@@ -54,12 +55,14 @@ log = logging.getLogger("repro.engine")
 @functools.lru_cache(maxsize=256)
 def _local_solve_fn(spec, method, blocksize, gamma, score, row_chunk,
                     row_balanced):
-    def f(w, h):
-        res = prune_matrix(
-            w, h, spec, method=method, blocksize=blocksize, gamma=gamma,
-            score=score, row_chunk=row_chunk, row_balanced=row_balanced)
-        return res.w, res.mask, res.loss
-    return jax.jit(f)
+    def prune_solve(w, h):
+        note_trace("solve")
+        with jax.named_scope("prune_solve"):
+            res = prune_matrix(
+                w, h, spec, method=method, blocksize=blocksize, gamma=gamma,
+                score=score, row_chunk=row_chunk, row_balanced=row_balanced)
+            return res.w, res.mask, res.loss
+    return jax.jit(prune_solve)
 
 
 @dataclasses.dataclass

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import note_trace
+
 
 # The Hessian products are f32 at full precision: a TPU's default f32
 # matmul is one bf16 pass, which rounds f32 activations before the
@@ -41,48 +43,54 @@ def token_outer_product(x: jax.Array) -> jax.Array:
 
 
 @jax.jit
-def _accum_update(h: jax.Array, count: jax.Array, x: jax.Array):
+def _prune_hessian_update(h: jax.Array, count: jax.Array, x: jax.Array):
     """Numerically stable streaming mean of 2xxᵀ over tokens.
 
     Keeps H as the *mean* over tokens seen so far: H_n = H_{n-1} * (n_prev/n)
     + 2 x xᵀ / n. Equivalent to dividing the total sum by total tokens.
     """
-    x32 = x.astype(jnp.float32)
-    b = x32.shape[1]
-    new_count = count + b
-    scale_old = count / new_count
-    h = h * scale_old + (2.0 / new_count) * jnp.matmul(
-        x32, x32.T, precision=HIGHEST)
-    return h, new_count
+    note_trace("hessian")
+    with jax.named_scope("prune_hessian"):
+        x32 = x.astype(jnp.float32)
+        b = x32.shape[1]
+        new_count = count + b
+        scale_old = count / new_count
+        h = h * scale_old + (2.0 / new_count) * jnp.matmul(
+            x32, x32.T, precision=HIGHEST)
+        return h, new_count
 
 
 @jax.jit
-def _accum_update_weighted(h: jax.Array, count: jax.Array, x: jax.Array,
-                           wts: jax.Array):
+def _prune_hessian_update_weighted(h: jax.Array, count: jax.Array,
+                                   x: jax.Array, wts: jax.Array):
     """Weighted streaming mean: H = Σ_t w_t · 2 x_t x_tᵀ / Σ_t w_t.
 
     Used for MoE expert linears where each expert only sees its routed
     tokens (weights are routing validity 0/1 or gate probabilities).
     """
-    x32 = x.astype(jnp.float32)
-    w32 = wts.astype(jnp.float32)
-    b = jnp.sum(w32)
-    new_count = count + b
-    denom = jnp.maximum(new_count, 1e-12)
-    scale_old = count / denom
-    xw = x32 * w32[None, :]
-    h = h * scale_old + (2.0 / denom) * jnp.matmul(
-        xw, x32.T, precision=HIGHEST)
-    return h, new_count
+    note_trace("hessian")
+    with jax.named_scope("prune_hessian"):
+        x32 = x.astype(jnp.float32)
+        w32 = wts.astype(jnp.float32)
+        b = jnp.sum(w32)
+        new_count = count + b
+        denom = jnp.maximum(new_count, 1e-12)
+        scale_old = count / denom
+        xw = x32 * w32[None, :]
+        h = h * scale_old + (2.0 / denom) * jnp.matmul(
+            xw, x32.T, precision=HIGHEST)
+        return h, new_count
 
 
 @jax.jit
-def _merge_many(hs: jax.Array, cs: jax.Array):
+def _prune_hessian_merge(hs: jax.Array, cs: jax.Array):
     """Weighted mean of stacked (S, m, m) Hessians by (S,) token counts."""
-    total = jnp.sum(cs)
-    h = (jnp.einsum("s,sij->ij", cs, hs, precision=HIGHEST)
-         / jnp.maximum(total, 1.0))
-    return jnp.where(total > 0, h, hs[0]), total
+    note_trace("hessian")
+    with jax.named_scope("prune_hessian"):
+        total = jnp.sum(cs)
+        h = (jnp.einsum("s,sij->ij", cs, hs, precision=HIGHEST)
+             / jnp.maximum(total, 1.0))
+        return jnp.where(total > 0, h, hs[0]), total
 
 
 @dataclasses.dataclass
@@ -110,7 +118,7 @@ class HessianAccumulator:
         """x: (m, B) — columns are calibration tokens for this layer."""
         if x.ndim != 2 or x.shape[0] != self.dim:
             raise ValueError(f"expected ({self.dim}, B) activations, got {x.shape}")
-        self.h, self.count = _accum_update(self.h, self.count, x)
+        self.h, self.count = _prune_hessian_update(self.h, self.count, x)
 
     def update_tokens(self, tokens_first: jax.Array) -> None:
         """Convenience for (num_tokens, m) layouts (batch*seq flattened)."""
@@ -127,7 +135,7 @@ class HessianAccumulator:
         if weights.shape != (x.shape[1],):
             raise ValueError(
                 f"weights {weights.shape} incompatible with x {x.shape}")
-        self.h, self.count = _accum_update_weighted(
+        self.h, self.count = _prune_hessian_update_weighted(
             self.h, self.count, x, weights)
 
     def merge(self, other: "HessianAccumulator") -> "HessianAccumulator":
@@ -155,8 +163,8 @@ class HessianAccumulator:
         if any(a.dim != dim for a in accs):
             raise ValueError(
                 f"cannot merge accumulators of dims {[a.dim for a in accs]}")
-        hs, cs = _merge_many(jnp.stack([a.h for a in accs]),
-                             jnp.stack([a.count for a in accs]))
+        hs, cs = _prune_hessian_merge(jnp.stack([a.h for a in accs]),
+                                      jnp.stack([a.count for a in accs]))
         return HessianAccumulator(dim, h=hs, count=cs)
 
     def finalize(self) -> jax.Array:

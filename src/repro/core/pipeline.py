@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.calibration import CalibrationSet
-from repro.obs import Obs
+from repro.obs import Obs, counting_traces, note_trace
 
 log = logging.getLogger("repro.pipeline")
 
@@ -91,11 +91,6 @@ class PipelineStats:
     segments: int = 0
     calib_shards: int = 1
     batches: int = 0
-    # distinct jitted stage callables built (trace-key × mode).  jax may
-    # still retrace one callable per input shape — e.g. uneven shard
-    # groups stack to two batch sizes — so this is a lower bound on XLA
-    # compilations, not an exact count.
-    compiles: int = 0
     capture_s: float = 0.0
     solve_s: float = 0.0
     propagate_s: float = 0.0
@@ -139,6 +134,15 @@ class SegmentScheduler:
     One instance lives for one ``run_pipelined`` call; jitted segment
     applies are cached by ``apply.trace_key`` (falling back to the apply
     object itself), so structurally identical segments share a compile.
+    Each run builds its jits anew, so every run traces them again
+    (``prune_stage_traces_total{stage}`` counts it).
+
+    Each stage runs in a live ``prune_<stage>`` span (``Tracer.span``:
+    a host event in a ``jax.profiler`` trace).  Its device programs are
+    named for the stage (``jit_prune_capture``, ``jit__prune_hessian_*``,
+    ``jit_prune_solve*``, ``jit_prune_propagate``: the XLA module names a
+    device trace shows) and carry it as a ``jax.named_scope`` in every
+    op's metadata.
     """
 
     def __init__(
@@ -163,9 +167,8 @@ class SegmentScheduler:
         self._instrument = instrument
         self._fns: Dict[Any, Callable] = {}
         # stage timing flows through the SAME obs registry/tracer the
-        # serve stack uses (ISSUE-8): prune_stage_seconds_total{stage}
-        # mirrors stats.<stage>_s, and every stage window becomes a
-        # trace span when the caller's bundle has tracing on
+        # serve stack uses: prune_stage_seconds_total{stage} mirrors
+        # stats.<stage>_s, and every stage window is a live span
         self.obs = obs if obs is not None else Obs.disabled()
         reg = self.obs.metrics
         self._stage_s = reg.counter(
@@ -174,30 +177,36 @@ class SegmentScheduler:
             "(capture/solve/propagate)", ("stage",))
         self._m_segments = reg.counter(
             "prune_segments_total", "Segments pruned")
-        self._m_compiles = reg.counter(
-            "prune_compiles_total",
-            "Distinct jitted stage callables built")
+        self._traces = reg.counter(
+            "prune_stage_traces_total",
+            "Traces of the prune stage programs, each with its lowering "
+            "and its compile or cache load "
+            "(capture/hessian/solve/propagate)", ("stage",))
 
     # ---------------------------------------------------------- timing
     @contextlib.contextmanager
-    def timed(self, stage: str, ready: Callable[[], Any] = lambda: ()):
-        """Accrue host time into ``stats.<stage>_s``; with instrumentation
-        on (or under the multi-device-CPU collective serialization), also
+    def timed(self, stage: str, ready: Callable[[], Any] = lambda: (),
+              **args):
+        """Run the block in a live ``prune_<stage>`` span (``args`` its
+        metadata), counting the stage programs traced in it, and accrue
+        its host time into ``stats.<stage>_s``; with instrumentation on
+        (or under the multi-device-CPU collective serialization), also
         block on ``ready()``'s arrays so the time is a true device cost
         instead of an async dispatch."""
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            if self._instrument or self.strict:
-                for leaf in jax.tree.leaves(ready()):
-                    jax.block_until_ready(leaf)
-            t1 = time.monotonic()
-            setattr(self.stats, f"{stage}_s",
-                    getattr(self.stats, f"{stage}_s") + t1 - t0)
-            self._stage_s.labels(stage=stage).inc(t1 - t0)
-            self.obs.tracer.complete(f"prune_{stage}", t0, t1,
-                                     track="prune")
+        with self.obs.tracer.span(f"prune_{stage}", track="prune",
+                                  args=args), \
+                counting_traces(self._traces):
+            t0 = time.monotonic()
+            try:
+                yield
+            finally:
+                if self._instrument or self.strict:
+                    for leaf in jax.tree.leaves(ready()):
+                        jax.block_until_ready(leaf)
+                t1 = time.monotonic()
+                setattr(self.stats, f"{stage}_s",
+                        getattr(self.stats, f"{stage}_s") + t1 - t0)
+                self._stage_s.labels(stage=stage).inc(t1 - t0)
 
     # -------------------------------------------------------- stacking
     def shard_states(self, per_batch_states: Sequence[Any]) -> List[Any]:
@@ -220,15 +229,20 @@ class SegmentScheduler:
         key = (getattr(seg.apply, "trace_key", seg.apply), capture)
         fn = self._fns.get(key)
         if fn is None:
-            self.stats.compiles += 1
-            self._m_compiles.inc()
+            apply = seg.apply
             if capture:
-                fn = jax.jit(
-                    lambda p, s, a=seg.apply: a(p, s, capture=True))
+                def prune_capture(p, s):
+                    note_trace("capture")
+                    with jax.named_scope("prune_capture"):
+                        return apply(p, s, capture=True)
+                fn = jax.jit(prune_capture)
             else:
-                fn = jax.jit(
-                    lambda p, s, a=seg.apply: a(p, s, capture=False)[0],
-                    donate_argnums=(1,) if self.donate else ())
+                def prune_propagate(p, s):
+                    note_trace("propagate")
+                    with jax.named_scope("prune_propagate"):
+                        return apply(p, s, capture=False)[0]
+                fn = jax.jit(prune_propagate,
+                             donate_argnums=(1,) if self.donate else ())
             self._fns[key] = fn
         return fn
 
@@ -239,27 +253,33 @@ class SegmentScheduler:
         apply per shard, and merge the per-shard Hessians (collective
         when the shard count matches the mesh's batch axes)."""
         fn = self._fn(seg, capture=True)
+        span = self.obs.tracer.span
+        where = {"segment": seg.name}
         sets: List[CalibrationSet] = []
         result: List[CalibrationSet] = []
         with self.timed(
                 "capture",
-                lambda: [a.h for s in result for a in s.accs.values()]):
+                lambda: [a.h for s in result for a in s.accs.values()],
+                **where):
             for st in shard_states:
                 _, caps = fn(seg_params, st)
                 if self.strict:
                     # per-shard programs are mutually independent — on
                     # multi-device CPU their collectives must not overlap
                     jax.block_until_ready(jax.tree.leaves(caps))
-                sets.append(CalibrationSet.from_captures(caps))
-            if len(sets) == 1:
-                merged = sets[0]
-            elif self.mesh is not None and self.dp_axes:
-                from repro.core.distributed import allreduce_calibration
+                with span("prune_hessian_accumulate", track="prune",
+                          args=where):
+                    sets.append(CalibrationSet.from_captures(caps))
+            with span("prune_hessian_merge", track="prune", args=where):
+                if len(sets) == 1:
+                    merged = sets[0]
+                elif self.mesh is not None and self.dp_axes:
+                    from repro.core.distributed import allreduce_calibration
 
-                merged = allreduce_calibration(sets, self.mesh,
-                                               axis_name=self.dp_axes)
-            else:
-                merged = CalibrationSet.merge_all(sets)
+                    merged = allreduce_calibration(sets, self.mesh,
+                                                   axis_name=self.dp_axes)
+                else:
+                    merged = CalibrationSet.merge_all(sets)
             result.append(merged)
         return merged
 
@@ -269,7 +289,7 @@ class SegmentScheduler:
         input hidden buffers; returns the next segment's inputs."""
         fn = self._fn(seg, capture=False)
         out: List[Any] = []
-        with self.timed("propagate", lambda: out):
+        with self.timed("propagate", lambda: out, segment=seg.name):
             for st in shard_states:
                 out.append(fn(seg_params, st))
                 if self.strict:
@@ -287,11 +307,33 @@ def run_pipelined(
     order, same skip/resume/checkpoint behavior (``progress_store`` saves
     land on segment boundaries), same reports — only the dispatch
     structure differs.
+
+    Live spans (track ``prune``): ``prune_job`` around the run, one
+    ``prune_segment`` per segment holding its ``prune_capture`` (with
+    ``prune_hessian_accumulate`` per shard and ``prune_hessian_merge``),
+    ``prune_solve`` (one ``prune_solve_linear`` per linear) and
+    ``prune_propagate``, and last ``prune_drain``, the host reading the
+    report scalars back.
     """
+    sched = SegmentScheduler(
+        mesh=engine.mesh,
+        calib_shard=engine.calib_shard,
+        instrument=instrument,
+        # engines wired with an obs bundle (launch/prune.py) surface
+        # stage seconds through the shared registry; bare engines no-op
+        obs=getattr(engine, "obs", None),
+    )
+    with sched.obs.tracer.span("prune_job", track="prune"):
+        return _run(engine, sched, params, calib_batches)
+
+
+def _run(engine, sched: SegmentScheduler, params: Any,
+         calib_batches: Sequence[Any]) -> Tuple[Any, List]:
     from repro.core.engine import LinearReport
 
     model = engine.model
     segments = model.prunable_segments()
+    span = sched.obs.tracer.span
 
     start_seg = 0
     if engine.progress_store is not None:
@@ -301,14 +343,6 @@ def run_pipelined(
             start_seg, params = resumed
             log.info("resuming pipelined pruning at segment %d", start_seg)
 
-    sched = SegmentScheduler(
-        mesh=engine.mesh,
-        calib_shard=engine.calib_shard,
-        instrument=instrument,
-        # engines wired with an obs bundle (launch/prune.py) surface
-        # stage seconds through the shared registry; bare engines no-op
-        obs=getattr(engine, "obs", None),
-    )
     t_wall = time.monotonic()
 
     init_fn = getattr(model, "calib_init", None) or model.first_hidden
@@ -324,68 +358,76 @@ def run_pipelined(
 
     for si in range(start_seg, len(segments)):
         seg = segments[si]
-        seg_params = seg.get_params(params)
+        where = {"segment": seg.name}
+        with span("prune_segment", track="prune",
+                  args={"index": si, **where}):
+            seg_params = seg.get_params(params)
 
-        calib = sched.capture(seg, seg_params, states)
+            calib = sched.capture(seg, seg_params, states)
 
-        linears = seg.linears
-        if linears is None:
-            linears = model.segment_linears(seg, seg_params)
-        seg_params_ref = [seg_params]
-        with sched.timed(
-                "solve",
-                lambda: ([r[1] for r in pending[-len(linears):]]
-                         + jax.tree.leaves(seg_params_ref[0]))):
-            for lin in linears:
-                if engine._should_skip(f"{seg.name}.{lin.name}"):
-                    continue
-                if lin.name not in calib.accs:
-                    raise KeyError(
-                        f"segment {seg.name}: no capture for linear "
-                        f"{lin.name!r} (captures: {sorted(calib.names())})")
-                w = lin.get(seg_params)
-                hmat = calib.hessian(lin.name)
-                t0 = time.monotonic()
-                # strict mode (multi-device CPU): the loss float() blocks
-                # the per-linear chain so no two collective programs are
-                # ever in flight together
-                res = engine._prune_one(w, hmat, sync=sched.strict)
-                seg_params = lin.set(seg_params, res.w)
-                seg_params_ref[0] = seg_params
-                pending.append((
-                    f"{seg.name}.{lin.name}",
-                    res.w,
-                    (res.mask, res.loss),
-                    time.monotonic() - t0,
-                    tuple(w.shape),
-                ))
+            linears = seg.linears
+            if linears is None:
+                linears = model.segment_linears(seg, seg_params)
+            seg_params_ref = [seg_params]
+            with sched.timed(
+                    "solve",
+                    lambda: ([r[1] for r in pending[-len(linears):]]
+                             + jax.tree.leaves(seg_params_ref[0])),
+                    **where):
+                for lin in linears:
+                    if engine._should_skip(f"{seg.name}.{lin.name}"):
+                        continue
+                    if lin.name not in calib.accs:
+                        raise KeyError(
+                            f"segment {seg.name}: no capture for linear "
+                            f"{lin.name!r} (captures: "
+                            f"{sorted(calib.names())})")
+                    with span("prune_solve_linear", track="prune",
+                              args={**where, "linear": lin.name}):
+                        w = lin.get(seg_params)
+                        hmat = calib.hessian(lin.name)
+                        t0 = time.monotonic()
+                        # strict mode (multi-device CPU): the loss float()
+                        # blocks the per-linear chain so no two collective
+                        # programs are ever in flight together
+                        res = engine._prune_one(w, hmat, sync=sched.strict)
+                        seg_params = lin.set(seg_params, res.w)
+                        seg_params_ref[0] = seg_params
+                        pending.append((
+                            f"{seg.name}.{lin.name}",
+                            res.w,
+                            (res.mask, res.loss),
+                            time.monotonic() - t0,
+                            tuple(w.shape),
+                        ))
 
-        params = seg.set_params(params, seg_params)
-        states = sched.propagate(seg, seg_params, states)
-        sched.stats.segments += 1
-        sched._m_segments.inc()
+            params = seg.set_params(params, seg_params)
+            states = sched.propagate(seg, seg_params, states)
+            sched.stats.segments += 1
+            sched._m_segments.inc()
 
-        if engine.progress_store is not None:
-            # the only mid-run host sync: checkpoints materialize params,
-            # always on a segment boundary
-            engine.progress_store.save(si + 1, params)
+            if engine.progress_store is not None:
+                # the only mid-run host sync: checkpoints materialize
+                # params, always on a segment boundary
+                engine.progress_store.save(si + 1, params)
 
     if engine.progress_store is not None:
         engine.progress_store.finalize()
 
     # materialize report scalars only now — the mask means / losses are
     # the run's only remaining device work, drained one float() at a time
-    reports = [
-        LinearReport(
-            name=name,
-            method=engine.method,
-            sparsity=float(jnp.mean(mask.astype(jnp.float32))),
-            recon_error=float(loss),
-            seconds=secs,
-            shape=shape,
-        )
-        for name, _, (mask, loss), secs, shape in pending
-    ]
+    with span("prune_drain", track="prune"):
+        reports = [
+            LinearReport(
+                name=name,
+                method=engine.method,
+                sparsity=float(jnp.mean(mask.astype(jnp.float32))),
+                recon_error=float(loss),
+                seconds=secs,
+                shape=shape,
+            )
+            for name, _, (mask, loss), secs, shape in pending
+        ]
     sched.stats.wall_s = time.monotonic() - t_wall
     engine.last_pipeline_stats = sched.stats
     return params, reports

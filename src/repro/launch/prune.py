@@ -139,9 +139,13 @@ def _run(args, cfg, obs: Obs) -> None:
               f"{s['total_recon_error']:.4f}")
         ps = engine.last_pipeline_stats
         if ps is not None:
+            traces = obs.metrics.get("prune_stage_traces_total")
+            traced = "".join(
+                f", {int(c.value)} {k[0]} trace(s)"
+                for k, c in (traces.children() if traces else []))
             print(f"pipeline: {ps.segments} segments, "
-                  f"{ps.calib_shards} calib shard(s), {ps.compiles} "
-                  f"jitted stage fn(s), wall {ps.wall_s:.2f}s")
+                  f"{ps.calib_shards} calib shard(s){traced}, "
+                  f"wall {ps.wall_s:.2f}s")
         print(f"{args.method} {args.sparsity} ppl: "
               f"{eval_ppl(model, pruned, pipe):.4f}")
     save_pytree(os.path.join(args.out, "pruned_params"), pruned,

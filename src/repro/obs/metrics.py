@@ -33,7 +33,9 @@ format (the frontend's ``GET /metrics``).
 from __future__ import annotations
 
 import bisect
+import contextvars
 import threading
+from contextlib import contextmanager
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 # default latency buckets: ~12% geometric spacing, 100µs .. ~80s.  The
@@ -490,3 +492,32 @@ class MetricsRegistry:
 
 
 NULL_REGISTRY = MetricsRegistry(enabled=False)
+
+
+# The family that ``note_trace`` counts into, for the code running in
+# this context (thread).  Jitted programs live at module level, or are
+# shared across engines by an lru_cache, so the counter cannot be bound
+# into them: the caller that runs a stage names its family here.
+_TRACE_FAMILY: contextvars.ContextVar[Optional[MetricFamily]] = \
+    contextvars.ContextVar("repro_trace_family", default=None)
+
+
+@contextmanager
+def counting_traces(family):
+    """Count the ``note_trace`` calls made inside this block into
+    ``family`` (a counter family labelled by ``stage``)."""
+    token = _TRACE_FAMILY.set(family)
+    try:
+        yield
+    finally:
+        _TRACE_FAMILY.reset(token)
+
+
+def note_trace(stage: str) -> None:
+    """Count one trace of a stage program.  Call it from the Python body
+    of a jitted function: that body runs only while JAX traces it, so
+    each trace (with its lowering and its compile or cache load) counts
+    once and a cached call counts nothing."""
+    fam = _TRACE_FAMILY.get()
+    if fam is not None:
+        fam.labels(stage=stage).inc()

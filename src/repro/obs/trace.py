@@ -1,4 +1,5 @@
-"""Request-lifecycle tracing with Chrome-trace JSON export.
+"""Request-lifecycle and prune-stage tracing: Chrome-trace JSON export,
+and live spans on the profiler's clock.
 
 A :class:`Tracer` accumulates events in the Chrome trace event format
 (the ``{"traceEvents": [...]}`` JSON that chrome://tracing and
@@ -17,8 +18,19 @@ Timestamps are microseconds relative to the tracer's construction,
 taken from ``time.monotonic()`` — only deltas matter to the viewer.
 ``pid`` is always 0; ``tid`` names the emitting replica/component so
 each one gets its own track.  A disabled tracer (``NULL_TRACER``)
-no-ops every call, which keeps token streams bit-identical with
+records nothing, which keeps token streams bit-identical with
 tracing on or off (pinned by tests/test_obs.py).
+
+Which calls reach the profiler: only the live :meth:`Tracer.span`.
+Every span, enabled tracer or not, also opens a
+``jax.profiler.TraceAnnotation`` of the same name whose metadata holds
+``track`` and the span's ``args``, so inside a ``jax.profiler`` trace
+it is a host event in the same ``.xplane.pb`` as the device ops, on
+the same clock (the prune stages, core/pipeline.py).  With no profiler
+session active that costs one TraceMe check.  The retroactive
+:meth:`complete`, :meth:`instant` and the async request events are
+Chrome-JSON only: they are stamped after the fact, which the profiler
+cannot take.
 """
 
 from __future__ import annotations
@@ -28,6 +40,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Tracer:
@@ -81,16 +95,18 @@ class Tracer:
     @contextmanager
     def span(self, name: str, *, track: str = "main",
              args: Optional[dict] = None):
-        """Context-manager span; zero-cost when disabled."""
-        if not self.enabled:
-            yield
-            return
-        start = time.monotonic()
-        try:
-            yield
-        finally:
-            self.complete(name, start, time.monotonic(),
-                          track=track, args=args)
+        """Live span: a profiler host event always (``track`` and
+        ``args`` as its metadata), a Chrome-JSON event when enabled."""
+        with TraceAnnotation(name, track=track, **(args or {})):
+            if not self.enabled:
+                yield
+                return
+            start = time.monotonic()
+            try:
+                yield
+            finally:
+                self.complete(name, start, time.monotonic(),
+                              track=track, args=args)
 
     def instant(self, name: str, *, track: str = "main",
                 args: Optional[dict] = None) -> None:

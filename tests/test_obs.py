@@ -442,16 +442,172 @@ def test_prune_pipeline_stage_metrics(tiny_lm):
                                 batch=8)
     eng = PruningEngine(model, "2:4", method="SM", blocksize=64)
     eng.obs = Obs.create(metrics=True, trace=True)
-    eng.run(params, calib)
+    _, reports = eng.run(params, calib)
     reg = eng.obs.metrics
     stage = reg.get("prune_stage_seconds_total")
     by_stage = {k[0]: c.value for k, c in stage.children()}
     assert {"capture", "solve", "propagate"} <= set(by_stage)
     assert all(v > 0 for v in by_stage.values())
     assert reg.get("prune_segments_total").total() > 0
-    assert reg.get("prune_compiles_total").total() > 0
+    traces = {k[0]: c.value
+              for k, c in reg.get("prune_stage_traces_total").children()}
+    assert traces["capture"] > 0 and traces["propagate"] > 0
     # registry seconds mirror the engine's own pipeline stats
     ps = eng.last_pipeline_stats
     assert by_stage["solve"] == pytest.approx(ps.solve_s, rel=1e-6)
     for st in ("capture", "solve", "propagate"):
         assert len(eng.obs.tracer.events(f"prune_{st}", ph="X")) > 0
+    # the live spans around and inside the stages, with their segment
+    tr = eng.obs.tracer
+    n_seg = model.cfg.num_layers
+    assert len(tr.events("prune_job", ph="X")) == 1
+    assert len(tr.events("prune_drain", ph="X")) == 1
+    segs = tr.events("prune_segment", ph="X")
+    assert [e["args"]["index"] for e in segs] == list(range(n_seg))
+    for name in ("prune_capture", "prune_hessian_merge", "prune_solve",
+                 "prune_propagate"):
+        assert [e["args"]["segment"] for e in tr.events(name, ph="X")] \
+            == [e["args"]["segment"] for e in segs]
+    assert len(tr.events("prune_hessian_accumulate", ph="X")) >= n_seg
+    lin = tr.events("prune_solve_linear", ph="X")
+    assert [f"{e['args']['segment']}.{e['args']['linear']}" for e in lin] \
+        == [r.name for r in reports]
+
+
+# ======================================================================
+# prune stages on the profiler's clock, and the retrace counter
+# ======================================================================
+def _profile_host_events(tmp_path, fn):
+    """Host events ``(name, stats)`` of a ``jax.profiler`` trace of
+    ``fn()``, read back from its ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    path, = tmp_path.glob("**/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [(e.name, dict(e.stats)) for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_is_a_profiler_host_event(tmp_path, enabled):
+    """A live span reaches the profiler's trace with its track and args,
+    whether or not the tracer records Chrome JSON."""
+    tr = Tracer(enabled=enabled)
+
+    def work():
+        with tr.span("obs_probe_span", track="prune",
+                     args={"segment": "period1"}):
+            jax.block_until_ready(jax.numpy.ones(4) * 2)
+
+    evs = [st for n, st in _profile_host_events(tmp_path, work)
+           if n == "obs_probe_span"]
+    assert evs == [{"track": "prune", "segment": "period1"}]
+    assert len(tr.events("obs_probe_span", ph="X")) == (1 if enabled else 0)
+
+
+def _toy_segment():
+    """A one-linear segment: y = x @ w, capturing x."""
+    from repro.core.engine import SegmentSpec
+
+    def apply(p, h, capture=False):
+        return h @ p, ({"lin": h} if capture else {})
+    return SegmentSpec(name="toy0", apply=apply, linears=[],
+                       get_params=lambda p: p, set_params=lambda p, s: s)
+
+
+def test_stage_traces_count_traces_not_calls():
+    from repro.core.pipeline import SegmentScheduler
+
+    obs = Obs.create()
+    sched = SegmentScheduler(obs=obs, donate=False)
+    seg, w = _toy_segment(), jax.numpy.eye(4)
+    fam = obs.metrics.get("prune_stage_traces_total")
+
+    def n():
+        return {k[0]: c.value for k, c in fam.children()}.get("propagate")
+
+    sched.propagate(seg, w, [jax.numpy.ones((2, 4))])
+    sched.propagate(seg, w, [jax.numpy.ones((2, 4))])
+    assert n() == 1
+    sched.propagate(seg, w, [jax.numpy.ones((3, 4))])
+    assert n() == 2
+
+
+def test_engine_retraces_stage_programs_every_run(tiny_lm):
+    """Each run builds a new scheduler, whose capture and propagate jits
+    are traced again: 2 traces a run after the first.  The solve and
+    Hessian programs are module-level and cached, so they trace once."""
+    from repro.core import PruningEngine
+    from repro.data import calibration_batches
+
+    model, params, _ = tiny_lm
+    calib = calibration_batches(model.cfg, n_samples=8, seq_len=32,
+                                batch=8)
+    eng = PruningEngine(model, "2:4", method="SM", blocksize=64)
+    eng.obs = Obs.create()
+
+    def counts():
+        fam = eng.obs.metrics.get("prune_stage_traces_total")
+        return {k[0]: c.value for k, c in fam.children()}
+
+    eng.run(params, calib)
+    first = counts()
+    assert first["capture"] == 1 and first["propagate"] == 1
+    eng.run(params, calib)
+    second = counts()
+    assert {k: v - first.get(k, 0) for k, v in second.items()
+            if v != first.get(k, 0)} == {"capture": 1, "propagate": 1}
+
+
+def _stage_lowerings(tiny_lm):
+    """Each stage program lowered at tiny size, by program name."""
+    import jax.numpy as jnp
+
+    from repro.core.distributed import _sharded_prune_fn
+    from repro.core.engine import _local_solve_fn
+    from repro.core.hessian import (_prune_hessian_merge,
+                                    _prune_hessian_update)
+    from repro.core.pipeline import SegmentScheduler
+    from repro.core.sparsity import SparsitySpec
+    from repro.dist import make_mesh
+
+    model, params, _ = tiny_lm
+    seg = model.prunable_segments()[0]
+    sp = seg.get_params(params)
+    init = getattr(model, "calib_init", None) or model.first_hidden
+    h = init(params, {"tokens": jnp.zeros((2, 16), jnp.int32)})
+    sched = SegmentScheduler(donate=False)
+    spec, m = SparsitySpec.parse("2:4"), 16
+    w, hm = jnp.ones((8, m)), jnp.eye(m)
+    return {
+        "prune_capture": lambda: sched._fn(seg, True).lower(sp, h),
+        "prune_propagate": lambda: sched._fn(seg, False).lower(sp, h),
+        "prune_solve": lambda: _local_solve_fn(
+            spec, "SM", 8, 0.01, None, None, False).lower(w, hm),
+        "prune_solve_rows": lambda: _sharded_prune_fn(
+            make_mesh((1,), ("model",)), spec, "SM", 8, 0.01, None, None,
+            "model").lower(w, hm),
+        "_prune_hessian_update": lambda: _prune_hessian_update.lower(
+            jnp.eye(m), jnp.zeros(()), jnp.ones((m, 4))),
+        "_prune_hessian_merge": lambda: _prune_hessian_merge.lower(
+            jnp.ones((2, m, m)), jnp.ones((2,))),
+    }
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("prune_capture", "prune_capture"),
+    ("_prune_hessian_update", "prune_hessian"),
+    ("_prune_hessian_merge", "prune_hessian"),
+    ("prune_solve", "prune_solve"),
+    ("prune_solve_rows", "prune_solve"),
+    ("prune_propagate", "prune_propagate"),
+])
+def test_stage_program_carries_its_scope(tiny_lm, program, scope):
+    """Every stage program runs as an XLA module named for its stage (the
+    "XLA Modules" line of a device trace) and names the stage in its
+    ops' metadata."""
+    text = _stage_lowerings(tiny_lm)[program]().as_text(debug_info=True)
+    assert f"module @jit_{program} " in text
+    assert f"/{scope}/" in text

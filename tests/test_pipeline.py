@@ -13,6 +13,7 @@ from repro.core.engine import summarize
 from repro.core.pipeline import SegmentScheduler, _resolve_shards
 from repro.data import calibration_batches
 from repro.dist import make_mesh
+from repro.obs import Obs
 
 
 @pytest.fixture(scope="module")
@@ -40,6 +41,7 @@ def test_pipelined_matches_serial(tiny_lm, calib):
         model, "2:4", method="SM", blocksize=64,
         pipeline="off").run(params, calib)
     eng = PruningEngine(model, "2:4", method="SM", blocksize=64)
+    eng.obs = Obs.create()
     got, reports = eng.run(params, calib)
 
     total = mismatched = 0
@@ -60,8 +62,10 @@ def test_pipelined_matches_serial(tiny_lm, calib):
     assert s.segments == model.cfg.num_layers
     assert s.calib_shards == 1          # no mesh → local accumulation
     assert s.batches == len(calib)
-    # all period segments share one capture + one propagate compile
-    assert s.compiles == 2
+    # all period segments share one capture + one propagate trace
+    traces = eng.obs.metrics.get("prune_stage_traces_total")
+    by_stage = {k[0]: c.value for k, c in traces.children()}
+    assert by_stage["capture"] == 1 and by_stage["propagate"] == 1
 
 
 def test_pipelined_unstructured_fallback(tiny_lm, calib):
