@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.calibration import CalibrationSet
+from repro.core.pruner import solve_path
 from repro.obs import Obs, counting_traces, note_trace
 
 log = logging.getLogger("repro.pipeline")
@@ -182,6 +183,11 @@ class SegmentScheduler:
             "Traces of the prune stage programs, each with its lowering "
             "and its compile or cache load "
             "(capture/hessian/solve/propagate)", ("stage",))
+        self._solve_paths = reg.counter(
+            "prune_solve_path_total",
+            "Layer solves by how their MRP compensation is solved "
+            "(bordered: one factor extended each column block; "
+            "resolve: any other)", ("path",))
 
     # ---------------------------------------------------------- timing
     @contextlib.contextmanager
@@ -384,6 +390,9 @@ def _run(engine, sched: SegmentScheduler, params: Any,
                             f"{sorted(calib.names())})")
                     with span("prune_solve_linear", track="prune",
                               args={**where, "linear": lin.name}):
+                        sched._solve_paths.labels(path=solve_path(
+                            engine.spec, engine.method,
+                            engine.row_balanced)).inc()
                         w = lin.get(seg_params)
                         hmat = calib.hessian(lin.name)
                         t0 = time.monotonic()

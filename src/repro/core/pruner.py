@@ -10,15 +10,23 @@ compensation solution.
   magnitude / wanda  score-only baselines (no compensation)
 
 Block loop (unstructured & N:M): the accumulated mask grows block by
-block, and 𝔐 compensation re-solves Eq. (13) against the FULL accumulated
+block, and 𝔐 compensation solves Eq. (13) against the FULL accumulated
 mask each block — previously pruned weights stay exactly zero while every
 unpruned weight (in ALL blocks, left included) keeps being refined. That
 is precisely the paper's fix for SparseGPT's frozen-left-columns drawback.
+
+Two paths compute that solve (:func:`solve_path`).  Where every row
+prunes a static count per column block (N:M, or row-balanced
+unstructured), the loop runs per chunk of rows and extends one Cholesky
+factor per row by a border each block (``mrp.mrp_border_rows``).
+Otherwise each block re-solves the whole accumulated mask
+(``mrp.mrp_compensate_mask``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -93,6 +101,73 @@ def _score_mask_block(
             sc, spec.pruned_per_row_block(s))
     nppb = int(round(wblk.shape[0] * s * spec.rate))
     return masks_lib.unstructured_mask_from_scores(sc, nppb)
+
+
+def solve_path(spec: SparsitySpec, method: str, row_balanced: bool) -> str:
+    """How Algorithm 1's MRP compensation is solved for a layer:
+    ``"bordered"`` when SM/MM prune a static count per row and column
+    block (N:M, or row-balanced unstructured), so each block extends the
+    previous factor; ``"resolve"`` for every other solve."""
+    if method in ("SM", "MM") and (spec.is_semi_structured or row_balanced):
+        return "bordered"
+    return "resolve"
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "spec", "method", "score_name", "blocksize", "row_balanced",
+    "row_chunk"))
+def _prune_bordered(w, h, hinv, spec, method, score_name, blocksize,
+                    row_balanced, row_chunk):
+    """Algorithm 1's block loop for static per-row counts: rows are
+    independent (Remark 4.2), so each chunk of rows runs every column
+    block, carrying its weights and the factor of its pruned set.
+
+    Returns (w, mask, losses) with losses (n, nblocks) per row."""
+    n, m = w.shape
+    nblocks = m // blocksize
+    per_blk = spec.pruned_per_row_block(blocksize)
+    k = nblocks * per_blk
+    nm = (spec.n, spec.m) if spec.is_semi_structured else None
+    hinv32 = hinv.astype(jnp.float32)
+    if row_chunk is None:
+        row_chunk = mrp.border_row_chunk(n, k, m, blocksize)
+
+    def prune_rows(w_rows):
+        c = w_rows.shape[0]
+        w_rows = w_rows.astype(jnp.float32)
+        linv = jnp.zeros((c, k, k), jnp.float32)
+        mask = jnp.zeros((c, m), bool)
+        idx = jnp.zeros((c, 0), jnp.int32)
+        losses = []
+        for b in range(nblocks):
+            c0 = b * blocksize
+            wblk = w_rows[:, c0:c0 + blocksize]
+            if method == "SM":
+                mblk = _score_mask_block(
+                    wblk, h, hinv, spec, score_name, c0, row_balanced)
+            else:  # MM
+                mblk = mrp.select_nm_mask_mrp(
+                    wblk, hinv[c0:c0 + blocksize, c0:c0 + blocksize],
+                    spec.n, spec.m)
+            mask = mask.at[:, c0:c0 + blocksize].set(mblk)
+            new, _ = masks_lib.padded_row_indices(mblk, per_blk)
+            idx = jnp.concatenate([idx, new + c0], axis=1)
+            linv, w_rows, loss = mrp.mrp_border_rows(
+                linv, w_rows, hinv32, idx, b * per_blk, c0, blocksize, nm)
+            w_rows = jnp.where(mask, 0.0, w_rows)
+            losses.append(loss)
+        return w_rows.astype(w.dtype), mask, jnp.stack(losses, axis=1)
+
+    if per_blk == 0:
+        return w, jnp.zeros((n, m), bool), jnp.zeros((n, nblocks))
+    if row_chunk is None or row_chunk >= n:
+        return prune_rows(w)
+    chunks = -(-n // row_chunk)
+    wp = jnp.pad(w, ((0, chunks * row_chunk - n), (0, 0)))
+    w_new, mask, losses = jax.lax.map(
+        prune_rows, wp.reshape(chunks, row_chunk, m))
+    return (w_new.reshape(-1, m)[:n], mask.reshape(-1, m)[:n],
+            losses.reshape(-1, nblocks)[:n])
 
 
 def prune_matrix(
@@ -172,37 +247,34 @@ def prune_matrix(
     # --- 𝔖𝔐 / 𝔐𝔐: Algorithm 1 block loop with MRP compensation ---------
     score_name = score or "obs"
     nblocks = m // blocksize
-    # static per-row bound when selection is row-balanced (incl. all N:M)
-    static_rows = spec.is_semi_structured or row_balanced
-    per_blk = spec.pruned_per_row_block(blocksize) if static_rows else None
-    # N:M masks prune exactly N of every group: the MRP submatrices are
-    # then built by an exact structured select instead of a gather
-    nm = (spec.n, spec.m) if spec.is_semi_structured else None
-    mask_acc = jnp.zeros((n, m), bool)
-    w_cur = w
     # Per-block Eq. (12) losses.  Each block's solve is against the FULL
     # accumulated mask, so entry b supersedes entry b-1 (it re-solves the
     # earlier blocks' weights too) — the honest scalar summary is the
     # FINAL solve's loss, not a sum or a silently-overwritten "total".
+    if solve_path(spec, method, row_balanced) == "bordered":
+        w_cur, mask_acc, losses = _prune_bordered(
+            w, h, hinv, spec=spec, method=method, score_name=score_name,
+            blocksize=blocksize, row_balanced=row_balanced,
+            row_chunk=row_chunk)
+        block_losses = list(jnp.sum(losses, axis=0))
+        return _mrp_result(w0, w_cur, mask_acc, h, method, spec,
+                           block_losses)
+    mask_acc = jnp.zeros((n, m), bool)
+    w_cur = w
     block_losses = []
     for b in range(nblocks):
         c0 = b * blocksize
         wblk = jax.lax.dynamic_slice(w_cur, (0, c0), (n, blocksize))
-        if method == "SM":
-            mblk = _score_mask_block(
-                wblk, h, hinv, spec, score_name, c0, row_balanced)
-        else:  # MM
-            hinv_blk = jax.lax.dynamic_slice(
-                hinv, (c0, c0), (blocksize, blocksize)
-            )
-            mblk = mrp.select_nm_mask_mrp(wblk, hinv_blk, spec.n, spec.m)
+        mblk = _score_mask_block(wblk, h, hinv, spec, score_name, c0)
         mask_acc = jax.lax.dynamic_update_slice(mask_acc, mblk, (0, c0))
         # MRP compensation against the FULL accumulated mask (Algorithm 1).
-        k_max = (b + 1) * per_blk if static_rows else None
         w_cur, loss_rows = mrp.mrp_compensate_mask(
-            w_cur, hinv, mask_acc, k_max=k_max, row_chunk=row_chunk, nm=nm
-        )
+            w_cur, hinv, mask_acc, row_chunk=row_chunk)
         block_losses.append(jnp.sum(loss_rows))
+    return _mrp_result(w0, w_cur, mask_acc, h, method, spec, block_losses)
+
+
+def _mrp_result(w0, w_cur, mask_acc, h, method, spec, block_losses):
     return PruneResult(
         w_cur,
         mask_acc,

@@ -156,3 +156,51 @@ def test_mm_mask_not_worse_than_sm_mask_on_average():
         return total
 
     assert group_loss(mask_m) <= group_loss(mask_s) + 1e-6
+
+
+@pytest.mark.parametrize("k", [1, 7, 32, 128])
+def test_lower_inverse(k):
+    """The doubling inverse of batched lower-triangular factors: lower
+    triangular, and M L = I at f32 accuracy (widths that are not powers
+    of two included)."""
+    a = jax.random.normal(jax.random.key(k), (3, k, 2 * k))
+    low = jnp.linalg.cholesky(jnp.einsum("cij,ckj->cik", a, a)
+                              + k * jnp.eye(k))
+    inv = np.asarray(mrp._lower_inverse(low), np.float64)
+    assert np.all(np.triu(inv, 1) == 0.0)
+    np.testing.assert_allclose(inv @ np.asarray(low, np.float64),
+                               np.broadcast_to(np.eye(k), (3, k, k)),
+                               atol=1e-5)
+
+
+def test_border_rows_extends_the_factor():
+    """Two bordered blocks give the same weights, loss and inverse
+    factor as the re-solve against both blocks' columns, and the
+    factor's leading block is that of the first block alone."""
+    n, m, bs = 6, 32, 16
+    hinv = dampened_inverse(random_psd_hessian(jax.random.key(9), m))
+    w = jax.random.normal(jax.random.key(10), (n, m))
+    mask = jnp.asarray(masks_lib.nm_mask_from_scores(
+        jax.random.uniform(jax.random.key(11), (n, m)), 2, 4))
+    idx, _ = masks_lib.padded_row_indices(mask, m // 2)
+    k1, k = bs // 2, m // 2
+    linv = jnp.zeros((n, k, k))
+    m1 = jnp.where(jnp.arange(m) < bs, mask, False)
+    w1, _ = mrp.mrp_compensate_mask(w, hinv, m1)
+    linv, w_b, _ = mrp.mrp_border_rows(linv, w, hinv, idx[:, :k1], 0, 0,
+                                       bs, (2, 4))
+    w_b = jnp.where(m1, 0.0, w_b)
+    np.testing.assert_allclose(np.asarray(w_b), np.asarray(w1), atol=1e-5)
+    linv, w_b, loss = mrp.mrp_border_rows(linv, w_b, hinv, idx, k1, bs,
+                                          bs, (2, 4))
+    w_b = jnp.where(mask, 0.0, w_b)
+    w2, loss2 = mrp.mrp_compensate_mask(w1, hinv, mask)
+    np.testing.assert_allclose(np.asarray(w_b), np.asarray(w2), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(loss), np.asarray(loss2),
+                               rtol=1e-4, atol=1e-6)
+    a = np.asarray(hinv, np.float64)[np.asarray(idx)[:, :, None],
+                                     np.asarray(idx)[:, None, :]]
+    eye = np.broadcast_to(np.eye(k), (n, k, k))
+    lv = np.asarray(linv, np.float64)
+    np.testing.assert_allclose(lv @ a @ np.swapaxes(lv, 1, 2), eye,
+                               atol=1e-5)

@@ -85,6 +85,22 @@ def test_pipelined_unstructured_fallback(tiny_lm, calib):
     assert abs(summarize(reports)["mean_sparsity"] - 0.5) < 0.02
 
 
+@pytest.mark.parametrize("spec,path", [("2:4", "bordered"),
+                                       ("0.5", "resolve")])
+def test_solve_path_counted_per_linear(tiny_lm, calib, spec, path):
+    """``prune_solve_path_total{path}`` counts every linear solve once,
+    under the path its spec takes: 2:4 extends one factor per column
+    block, unstructured with a global count re-solves."""
+    model, params, _ = tiny_lm
+    eng = PruningEngine(model, spec, method="SM", blocksize=64)
+    eng.obs = Obs.create()
+    _, reports = eng.run(params, calib)
+    fam = eng.obs.metrics.get("prune_solve_path_total")
+    counts = {k[0]: c.value for k, c in fam.children()}
+    assert counts == {path: len(reports)}
+    assert len(reports) == 7 * model.cfg.num_layers
+
+
 def test_pipeline_resume_on_segment_boundary(tiny_lm, calib, tmp_path):
     """Interrupt mid-run → every checkpoint lands on a segment boundary
     (params identical to the uninterrupted run's state after the same

@@ -115,3 +115,80 @@ def test_sm_compensation_updates_left_blocks(problem):
     a = np.asarray(res1.w)[:, :64][m0]
     b = np.asarray(res_first.w)[:, :64][m0]
     assert np.abs(a - b).max() > 1e-6
+
+
+def _resolve_oracle(w, h, spec, method, blocksize, row_balanced):
+    """Algorithm 1 with a full re-solve each column block: a loop of
+    ``mrp.mrp_compensate_mask`` against the accumulated mask, each block
+    selected from the oracle's own weights.  Returns the weights, mask
+    and per-block losses, and the weights before the last block."""
+    from repro.core import mrp
+    from repro.core.hessian import dampened_inverse
+    from repro.core.pruner import _score_mask_block
+
+    spec = SparsitySpec.parse(spec)
+    hinv = dampened_inverse(h)
+    n, m = w.shape
+    mask = jnp.zeros((n, m), bool)
+    losses, w_prev = [], w
+    for c0 in range(0, m, blocksize):
+        wblk = w[:, c0:c0 + blocksize]
+        if method == "SM":
+            mblk = _score_mask_block(wblk, h, hinv, spec, "obs", c0,
+                                     row_balanced)
+        else:
+            mblk = mrp.select_nm_mask_mrp(
+                wblk, hinv[c0:c0 + blocksize, c0:c0 + blocksize],
+                spec.n, spec.m)
+        mask = mask.at[:, c0:c0 + blocksize].set(mblk)
+        w_prev = w
+        w, loss = mrp.mrp_compensate_mask(w, hinv, mask)
+        losses.append(float(jnp.sum(loss)))
+    return w, mask, losses, w_prev
+
+
+@pytest.mark.parametrize("spec,method,row_balanced", [
+    ("2:4", "SM", False), ("2:4", "MM", False), ("0.5", "SM", True)])
+def test_bordered_matches_resolve_loop(spec, method, row_balanced):
+    """The bordered factor (one Cholesky extended each column block) is
+    the same Eq. (13) solution as re-solving the whole accumulated mask
+    every block: weights, masks and losses agree at f32 tolerance, on 5
+    column blocks, m > n (mlp.wo's aspect) and a row chunk that does not
+    divide n; and the last block matches the float64 per-row oracle."""
+    from repro.core import mrp
+    from repro.core.hessian import dampened_inverse_np
+    from repro.core.pruner import solve_path
+
+    n, m, bs = 22, 320, 64
+    w = jax.random.normal(jax.random.key(7), (n, m)) * (
+        1.0 + jnp.arange(m)[None, :] / m)
+    h = random_psd_hessian(jax.random.key(8), m)
+    assert solve_path(SparsitySpec.parse(spec), method,
+                      row_balanced) == "bordered"
+    res = prune_matrix(w, h, spec, method=method, blocksize=bs,
+                       row_chunk=5, row_balanced=row_balanced)
+    w_ref, mask_ref, losses_ref, w_prev = _resolve_oracle(
+        w, h, spec, method, bs, row_balanced)
+
+    np.testing.assert_array_equal(np.asarray(res.mask), np.asarray(mask_ref))
+    np.testing.assert_allclose(np.asarray(res.w), np.asarray(w_ref),
+                               atol=2e-5)
+    np.testing.assert_allclose(res.stats["block_mrp_losses"], losses_ref,
+                               rtol=1e-4)
+    assert res.stats["final_mrp_loss"] == res.stats["block_mrp_losses"][-1]
+    assert bool(jnp.all(jnp.where(res.mask, res.w, 0.0) == 0.0))
+
+    hinv64 = dampened_inverse_np(np.asarray(h, np.float64))
+    mask = np.asarray(res.mask)
+    for q in (0, 11, n - 1):
+        row, _ = mrp.mrp_row_reference(
+            np.asarray(w_prev)[q], hinv64, np.where(mask[q])[0])
+        np.testing.assert_allclose(np.asarray(res.w)[q], row, atol=1e-4)
+
+
+def test_global_unstructured_takes_resolve():
+    from repro.core.pruner import solve_path
+
+    for spec, method, rb in [("0.5", "SM", False), ("2:4", "SS", False),
+                             ("2:4", "MS", False), ("0.5", "wanda", True)]:
+        assert solve_path(SparsitySpec.parse(spec), method, rb) == "resolve"
