@@ -22,6 +22,7 @@ ARCH_IDS = (
     "jamba_1_5_large_398b",
     "xlstm_350m",
     "seamless_m4t_large_v2",
+    "mamba_2_8b",
     # the paper's own evaluation family (CPU-scale analogue)
     "paper_tiny_lm",
 )
@@ -39,6 +40,7 @@ _ALIAS.update({
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "xlstm-350m": "xlstm_350m",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
+    "mamba-2.8b": "mamba_2_8b",
 })
 
 

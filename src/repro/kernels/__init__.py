@@ -5,6 +5,7 @@
   nm_select      Eq. (12) per-group combination scoring → 𝔐 mask (pruning)
   flash_attn     online-softmax causal attention (32k prefill)
   paged_attn     block-table paged GQA decode attention (serve runtime)
+  selective_scan Mamba-1 scan, state in VMEM (capture/propagate, prefill)
 
 Each kernel has a pure-jnp oracle in ref.py and a jit'd public wrapper in
 ops.py.  On this CPU container they are validated with interpret=True;

@@ -10,8 +10,9 @@ globals to mutate — ISSUE-7 api_redesign):
 - ``force_pallas`` (env ``JAX_PALLAS_INTERPRET=1`` — the CI tier-1
   kernel step) forces the Pallas kernel BODIES, in interpret mode,
   through every dispatch that would otherwise take a jnp-oracle
-  shortcut off-TPU (``paged_attention`` below), so kernels/
-  paged_attn.py logic is exercised on CPU-only runners.
+  shortcut off-TPU (``paged_attention``, ``nm_matmul`` and
+  ``selective_scan`` below), so the kernels' logic is exercised on
+  CPU-only runners.
 
 Tests and callers that need a specific mode use the
 :func:`override_dispatch` context manager instead of monkeypatching:
@@ -37,6 +38,8 @@ from repro.kernels.hessian_accum import hessian_accum as _hessian
 from repro.kernels.nm_select import nm_select as _nm_select
 from repro.kernels.nm_spmm import nm_spmm as _nm_spmm
 from repro.kernels.nm_spmm import nm_spmm_decode as _nm_spmm_decode
+from repro.kernels.selective_scan import selective_scan as _selective_scan
+from repro.kernels.selective_scan import selective_scan_chunked
 
 
 @dataclasses.dataclass(frozen=True)
@@ -244,3 +247,29 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     o = _flash(qp, kp, vp, bq=bq, bk=bk, causal=causal,
                interpret=dispatch_mode().interpret)
     return o[:, :t, :]
+
+
+def selective_scan(dt: jax.Array, x: jax.Array, b: jax.Array, c: jax.Array,
+                   a: jax.Array, init: Optional[jax.Array] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """Mamba-1 selective scan: (y (B, T, Di), last state (B, Di, N)) in
+    f32 from dt, x (B, T, Di), b, c (B, T, N), a (Di, N) and an optional
+    carry-in state (see ``kernels/selective_scan.py``).
+
+    Dispatch: the Pallas kernel on TPU (or forced, interpreted, by
+    ``JAX_PALLAS_INTERPRET=1`` / ``override_dispatch``); the chunked jnp
+    scan otherwise, and also inside a multi-device mesh, which cannot
+    partition a Mosaic kernel.  Each trace counts its path into
+    ``ssm_scan_path_total{path}`` (``repro.obs.note_scan_path``).
+    """
+    from repro.dist import current_ctx
+    from repro.obs import note_scan_path
+
+    mode = dispatch_mode()
+    ctx = current_ctx()
+    use_kernel = mode.force_pallas or (
+        not mode.interpret and (ctx is None or ctx.mesh.size == 1))
+    note_scan_path("pallas" if use_kernel else "chunked")
+    if not use_kernel:
+        return selective_scan_chunked(dt, x, b, c, a, init)
+    return _selective_scan(dt, x, b, c, a, init, interpret=mode.interpret)

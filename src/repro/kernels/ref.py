@@ -182,3 +182,26 @@ def flash_attn_ref(q: jax.Array, k: jax.Array, v: jax.Array,
         s = jnp.where(mask[None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bts,bsd->btd", p, v.astype(jnp.float32))
+
+
+def selective_scan_ref(dt: jax.Array, x: jax.Array, b: jax.Array,
+                       c: jax.Array, a: jax.Array, init=None):
+    """Mamba-1 selective scan, one time step after another in f32:
+    s_t = exp(dt_t A) s_{t-1} + dt_t x_t b_t, y_t = s_t c_t.
+    dt, x: (B, T, Di); b, c: (B, T, N); a: (Di, N); init (B, Di, N).
+    Returns (y (B, T, Di), last state (B, Di, N))."""
+    f32 = jnp.float32
+    dt, x, b, c, a = (v.astype(f32) for v in (dt, x, b, c, a))
+    s0 = (jnp.zeros((dt.shape[0], dt.shape[2], b.shape[2]), f32)
+          if init is None else init.astype(f32))
+
+    def step(s, xs):
+        dt_t, x_t, b_t, c_t = xs                      # (B,Di) / (B,N)
+        s = (jnp.exp(dt_t[..., None] * a) * s
+             + (dt_t * x_t)[..., None] * b_t[:, None, :])
+        return s, jnp.einsum("bdn,bn->bd", s, c_t,
+                             precision=jax.lax.Precision.HIGHEST)
+
+    last, ys = jax.lax.scan(step, s0, tuple(
+        jnp.swapaxes(v, 0, 1) for v in (dt, x, b, c)))
+    return jnp.swapaxes(ys, 0, 1), last

@@ -83,6 +83,10 @@ class ArchConfig:
     # numerics / training
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
+    # keep the residual stream (embedding output and every block's
+    # ``h + out``) in f32 while the blocks compute in ``dtype``, as the
+    # published Mamba does; mamba blocks only
+    residual_in_fp32: bool = False
     remat: str = "none"                     # none | full
     scan_layers: bool = True                # lax.scan over periods (False:
                                             # unrolled — cost-analysis runs)
@@ -96,6 +100,12 @@ class ArchConfig:
             raise ValueError(
                 f"{self.name}: {self.num_layers} layers != "
                 f"{len(self.prefix)} prefix + k*{len(self.period)} period")
+        if self.residual_in_fp32 and (
+                set(self.prefix + self.period) != {"mamba"}
+                or self.ssm_mlp or self.encdec):
+            raise ValueError(
+                f"{self.name}: residual_in_fp32 is implemented for stacks "
+                f"of mamba blocks without an MLP only")
 
     @property
     def hd(self) -> int:

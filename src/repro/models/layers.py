@@ -635,6 +635,8 @@ def embed_apply(p, tokens, cfg: ArchConfig):
     h = p["tok"][tokens]
     if cfg.embed_scale:
         h = h * jnp.asarray(math.sqrt(cfg.d_model), h.dtype)
+    if cfg.residual_in_fp32:
+        h = h.astype(jnp.float32)
     return h
 
 
@@ -661,6 +663,8 @@ HEAD_GATHER = False
 
 def unembed_apply(p, embed_p, h, cfg: ArchConfig):
     h = rmsnorm(p["ln"], h, cfg.norm_eps)
+    if cfg.residual_in_fp32:
+        h = h.astype(p["ln"]["scale"].dtype)
     if HEAD_GATHER:
         h = _dp_only_constrain(h)
     if cfg.tie_embeddings:

@@ -7,7 +7,9 @@ paths — full-sequence (training / prefill) and single-token decode with
 an explicit recurrent-state cache (the reason SSM archs run long_500k:
 state is O(1) in sequence length).
 
-Mamba-1 (Gu & Dao 2023):  selective SSM, associative-scan parallel form.
+Mamba-1 (Gu & Dao 2023):  selective SSM; the scan carries its state
+                           over T (a Pallas kernel on TPU, chunks of an
+                           associative scan elsewhere).
 mLSTM  (Beck et al. 2024): matrix-memory LSTM, attention-like parallel
                            form over T, stabilized exponential gating.
 sLSTM  (Beck et al. 2024): scalar-memory recurrent LSTM with block-diag
@@ -59,25 +61,17 @@ def mamba_init(key, cfg: ArchConfig, dtype) -> Params:
 
 
 def _mamba_ssm_scan(dt, x, b, c, a, init=None):
-    """Selective-scan core, parallel over T via associative_scan.
+    """Selective-scan core: ``kernels.ops.selective_scan`` (the Pallas
+    kernel on TPU, a jnp scan over T in chunks elsewhere), under the
+    ``ssm_scan`` scope.
 
     dt, x: (B,T,Di) f32;  b, c: (B,T,N) f32;  a: (Di,N) f32 (negative).
-    ``init`` (B,Di,N): carry-in state (chunked prefill continuation) —
-    the scan's cumulative-decay component replays it as ``A_{1..t}·s0``.
+    ``init`` (B,Di,N): carry-in state (chunked prefill continuation).
     Returns (y: (B,T,Di), last state (B,Di,N)).
     """
-    abar = jnp.exp(dt[..., None] * a[None, None])          # (B,T,Di,N)
-    bx = (dt * x)[..., None] * b[:, :, None, :]            # (B,T,Di,N)
-
-    def combine(e1, e2):
-        a1, b1 = e1
-        a2, b2 = e2
-        return a2 * a1, a2 * b1 + b2
-
-    aprod, states = jax.lax.associative_scan(combine, (abar, bx), axis=1)
-    if init is not None:
-        states = states + aprod * init[:, None]
-    return jnp.einsum("btdn,btn->btd", states, c), states[:, -1]
+    from repro.kernels import ops
+    with jax.named_scope("ssm_scan"):
+        return ops.selective_scan(dt, x, b, c, a, init)
 
 
 def mamba_apply(
@@ -104,7 +98,10 @@ def mamba_apply(
     """
     di, n, r, ck = cfg.d_inner, cfg.ssm_state, cfg.dt_rank, cfg.ssm_conv
     bsz, t, _ = h.shape
-    h_in = rmsnorm(p["ln"], h, cfg.norm_eps)
+    # the mixer computes in the model dtype; with ``residual_in_fp32``
+    # the residual stream ``h`` is f32 and so is ``h + out``
+    dtype = jnp.dtype(cfg.dtype) if cfg.residual_in_fp32 else h.dtype
+    h_in = rmsnorm(p["ln"], h, cfg.norm_eps).astype(dtype)
     xz = linear(h_in, p["in_proj"], caps=caps, name=f"{prefix}in_proj")
     x, z = jnp.split(xz, 2, axis=-1)                       # (B,T,Di) each
 
@@ -142,10 +139,10 @@ def mamba_apply(
     xc = xc + p["conv_b"].astype(jnp.float32)[None, None]
     xc = jax.nn.silu(xc)
 
-    dbc = linear(xc.astype(h.dtype), p["x_proj"], caps=caps,
+    dbc = linear(xc.astype(dtype), p["x_proj"], caps=caps,
                  name=f"{prefix}x_proj").astype(jnp.float32)
     dt_r, b, c = jnp.split(dbc, [r, r + n], axis=-1)
-    dt = linear(dt_r.astype(h.dtype), p["dt_proj"], caps=caps,
+    dt = linear(dt_r.astype(dtype), p["dt_proj"], caps=caps,
                 name=f"{prefix}dt_proj").astype(jnp.float32)
     dt = jax.nn.softplus(dt + p["dt_bias"][None, None])
     a = -jnp.exp(p["a_log"])                               # (Di,N)
@@ -188,9 +185,9 @@ def mamba_apply(
 
     y = y + p["d"].astype(jnp.float32)[None, None] * xc
     y = y * jax.nn.silu(z.astype(jnp.float32))
-    out = linear(y.astype(h.dtype), p["out_proj"], caps=caps,
+    out = linear(y.astype(dtype), p["out_proj"], caps=caps,
                  name=f"{prefix}out_proj")
-    return h + out, new_cache
+    return h + out.astype(h.dtype), new_cache
 
 
 def mamba_cache_init(cfg: ArchConfig, batch, dtype):

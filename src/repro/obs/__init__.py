@@ -17,14 +17,14 @@ from dataclasses import dataclass, replace
 
 from .metrics import (COUNT_BUCKETS, LATENCY_BUCKETS, NULL_REGISTRY,
                       MetricsRegistry, counting_traces, exp_buckets,
-                      note_trace)
+                      note_scan_path, note_trace)
 from .trace import NULL_TRACER, Tracer
 
 __all__ = [
     "Obs", "MetricsRegistry", "Tracer",
     "NULL_REGISTRY", "NULL_TRACER",
     "LATENCY_BUCKETS", "COUNT_BUCKETS", "exp_buckets",
-    "counting_traces", "note_trace",
+    "counting_traces", "note_scan_path", "note_trace",
 ]
 
 

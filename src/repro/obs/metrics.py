@@ -521,3 +521,17 @@ def note_trace(stage: str) -> None:
     fam = _TRACE_FAMILY.get()
     if fam is not None:
         fam.labels(stage=stage).inc()
+
+
+def note_scan_path(path: str) -> None:
+    """Count one trace of the Mamba selective scan by the path it takes
+    (``pallas`` or ``chunked``) into ``ssm_scan_path_total{path}``, in
+    the registry whose ``counting_traces`` block is running (the prune
+    stage programs); outside one nothing is counted.  Like
+    :func:`note_trace`, it runs only while JAX traces."""
+    reg = getattr(_TRACE_FAMILY.get(), "registry", None)
+    if reg is not None:
+        reg.counter("ssm_scan_path_total",
+                    "Traces of the Mamba selective scan by path "
+                    "(pallas: the TPU kernel; chunked: the jnp scan)",
+                    ("path",)).labels(path=path).inc()

@@ -1,5 +1,7 @@
 """The main path's Pallas kernels compiled for a described TPU v5e chip at
-Qwen1.5-0.5B widths (16 KV heads of 64, d 1024, d_ff 2816).
+Qwen1.5-0.5B widths (16 KV heads of 64, d 1024, d_ff 2816), and the
+selective scan at the Mamba-2.8B prune cell's (8 x 2048 tokens, d_inner
+5120, d_state 16).
 
 Interpret mode and the CPU tests never run Mosaic, which refuses blocks
 that break the TPU tiling rules; these compiles do (``interpret=False``,
@@ -18,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.nm_spmm import nm_spmm, nm_spmm_decode
 from repro.kernels.paged_attn import paged_attn
+from repro.kernels.selective_scan import selective_scan
 from repro.utils.hlo import tpu_kernel_names
 
 KV, HD, PAGE, P_MAX, BATCH, PAGES = 16, 64, 16, 64, 8, 513
@@ -79,3 +82,14 @@ def test_nm_spmm_compiles(one_chip, k, n):
     hlo = _compile(one_chip, nm_spmm, ((256, k), jnp.bfloat16),
                    ((k // 2, n), jnp.bfloat16), ((k // 2, n), jnp.int8))
     assert tpu_kernel_names(hlo) == ["nm_spmm"]
+
+
+@pytest.mark.parametrize("carry", [False, True])
+def test_selective_scan_compiles(one_chip, carry):
+    b, t, di, n = 8, 2048, 5120, 16
+    shapes = [((b, t, di), jnp.float32)] * 2 + [((b, t, n), jnp.float32)] * 2
+    shapes.append(((di, n), jnp.float32))
+    if carry:
+        shapes.append(((b, di, n), jnp.float32))
+    hlo = _compile(one_chip, selective_scan, *shapes)
+    assert tpu_kernel_names(hlo) == ["selective_scan"]
